@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from repro.kernels import autotune
 from repro.kernels.grouped_gemm import count_live_group_tiles
 from repro.kernels.ops import selective_scan_op
+from repro.launch.roofline import get_hw
 from repro.models.moe import moe_ffn
 from repro.models.ssm import mamba1_scan
 
@@ -244,7 +245,8 @@ def bench_autotune(shape, repeat):
     assert default_blocks in cands, (default_blocks, cands)
     res = autotune.autotune(
         "scan", {"T": T, "di": di, "N": N, "dtype": "float32"}, cands, run,
-        predict_fn=lambda b: autotune.predict_scan(b, T=T, di=di, N=N),
+        predict_fn=lambda b: autotune.predict_scan(
+            b, T=T, di=di, N=N, hw=get_hw("TPU v5 lite")),
         prune=2.0, repeat=repeat, use_cache=False)
     by_blocks = {tuple(c["blocks"]): c for c in res["candidates"]}
     default = by_blocks[default_blocks]

@@ -42,12 +42,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax.shard_map landed in 0.5.x; older releases ship it as experimental.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax version
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.core.rearrangement import Rearrangement
 from repro.utils import round_up as _round_up
 
@@ -296,7 +290,7 @@ def apply_comm_plan(
             allx = jax.lax.all_gather(xs, axis_name=axis, tiled=True)
             return masked(jnp.take(allx, gg[0], axis=0), mask[0])
 
-        return _shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(row, row, row), out_specs=row
         )(x, plan_arrays["global_gather"], plan_arrays["post_mask"])
 
@@ -312,18 +306,12 @@ def apply_comm_plan(
             recv = recv.reshape((d * chunk_cap,) + feat)
             return masked(jnp.take(recv, post[0], axis=0), mask[0])
 
-        return _shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(row, row, row, row), out_specs=row
         )(x, plan_arrays["pre_gather_dense"], plan_arrays["post_gather_dense"],
           plan_arrays["post_mask"])
 
     if mode == "ragged":
-        if not hasattr(jax.lax, "ragged_all_to_all"):
-            raise NotImplementedError(
-                f"mode='ragged' needs jax.lax.ragged_all_to_all "
-                f"(unavailable in jax {jax.__version__}); use mode='a2a'"
-            )
-
         def body(xs, pg, io, ss, oo, rs, post, mask):
             send = jnp.take(xs, pg[0], axis=0)
             out = jnp.zeros((cap_out,) + feat, xs.dtype)
@@ -335,7 +323,7 @@ def apply_comm_plan(
             )
             return masked(jnp.take(out, post[0], axis=0), mask[0])
 
-        return _shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(row,) + (row,) * 7, out_specs=row
         )(
             x,

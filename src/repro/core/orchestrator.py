@@ -21,6 +21,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
+import zlib
 from typing import Sequence
 
 import numpy as np
@@ -41,8 +42,10 @@ def _ex_rng(seed: int, sid: int, tag: str) -> np.random.Generator:
     """Per-example deterministic content: the SAME example yields the
     same tokens/embeddings wherever the rearrangement places it.  This
     is what makes consequence-invariance (paper S3.3) *testable*: loss
-    and gradients must be bit-identical under any balancing choice."""
-    return np.random.default_rng(abs(hash((seed, sid, tag))) % (2**63))
+    and gradients must be bit-identical under any balancing choice.
+    Seeded from integers only (a CRC of ``tag``): ``hash`` of a str is
+    salted per process, which would give every process other data."""
+    return np.random.default_rng((seed, sid, zlib.crc32(tag.encode())))
 
 __all__ = [
     "Capacities",

@@ -13,8 +13,8 @@ calibrate-against-measurement philosophy one level down:
      overhead term) and PRUNE candidates predicted far off the best --
      the model is there to keep the sweep cheap, not to decide,
   3. measure wall time for the survivors and pick the winner,
-  4. cache the winner per (kernel, shape signature, dtype, backend) in
-     a JSON file consulted at trace time by the call sites
+  4. cache the winner per (kernel, shape signature, dtype, device
+     kind) in a JSON file consulted at trace time by the call sites
      (``resolve``), with an explicit-override escape hatch
      (``REPRO_KERNEL_BLOCKS`` env var) that always wins.
 
@@ -32,7 +32,8 @@ from typing import Callable, Mapping, Sequence
 
 import numpy as np
 
-from repro.launch.roofline import HW, get_hw
+from repro.launch.roofline import HW
+from repro.utils import CHECKOUT_CACHE
 
 __all__ = [
     "Candidate", "autotune", "resolve", "cache_key", "default_cache_path",
@@ -60,13 +61,20 @@ def default_cache_path() -> str:
     env = os.environ.get(_ENV_CACHE)
     if env:
         return env
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "repro_autotune.json")
+    return str(CHECKOUT_CACHE / "autotune.json")
 
 
 def cache_key(kernel: str, key: Mapping[str, object]) -> str:
     parts = [kernel] + [f"{k}={key[k]}" for k in sorted(key)]
     return "|".join(parts)
+
+
+def _device_key(kernel: str, key: Mapping[str, object]) -> str:
+    """Cache key of ``key`` on the attached device: a winner timed on
+    one device kind (or in the interpreter) never applies to another."""
+    import jax
+
+    return cache_key(kernel, {**key, "device": jax.devices()[0].device_kind})
 
 
 def _load_cache(path: str) -> dict:
@@ -141,7 +149,7 @@ def resolve(
         _count_resolve(kernel, "disabled")
         return default
     entry = _load_cache(cache_path or default_cache_path()).get(
-        cache_key(kernel, key))
+        _device_key(kernel, key))
     if entry is None:
         _count_resolve(kernel, "miss")
         return default
@@ -172,7 +180,7 @@ def autotune(
     ``{"blocks", "predicted_s", "measured_ms", "candidates", "cached"}``.
     """
     path = cache_path or default_cache_path()
-    ck = cache_key(kernel, key)
+    ck = _device_key(kernel, key)
     if use_cache:
         hit = _load_cache(path).get(ck)
         if hit is not None:
@@ -248,11 +256,10 @@ def _roofline_s(flops: float, mem_bytes: float, grid_steps: float,
 
 
 def predict_flash(blocks, *, heads: int, Tq: int, Tkv: int, D: int,
-                  live_frac: float = 1.0, dtype_bytes: int = 2,
-                  hw: HW | None = None) -> float:
+                  hw: HW, live_frac: float = 1.0,
+                  dtype_bytes: int = 2) -> float:
     """Forward-pass roofline: 4*Tq*Tkv*D MACs over the live tiles, K/V
     tiles re-streamed once per live (q-tile, kv-tile) pair."""
-    hw = hw or get_hw()
     bq, bk = blocks
     tiles = (Tq // bq) * (Tkv // bk) * live_frac
     flops = 4.0 * heads * tiles * bq * bk * D
@@ -261,12 +268,11 @@ def predict_flash(blocks, *, heads: int, Tq: int, Tkv: int, D: int,
     return _roofline_s(flops, mem, heads * tiles, hw)
 
 
-def predict_scan(blocks, *, T: int, di: int, N: int, dtype_bytes: int = 4,
-                 hw: HW | None = None) -> float:
+def predict_scan(blocks, *, T: int, di: int, N: int, hw: HW,
+                 dtype_bytes: int = 4) -> float:
     """Recurrence is bandwidth/latency bound: stream u/dt/y (+B/C per
     channel block) once, plus a chunk-boundary state checkpoint; the
     per-grid-step overhead is what penalizes tiny chunks."""
-    hw = hw or get_hw()
     bd, ct = blocks
     n_d, n_t = di // bd, T // ct
     flops = 8.0 * T * di * N
@@ -279,11 +285,10 @@ def predict_scan(blocks, *, T: int, di: int, N: int, dtype_bytes: int = 4,
 
 
 def predict_grouped(blocks, *, M: int, K: int, N: int, E: int,
-                    live_tiles: int | None = None, dtype_bytes: int = 2,
-                    hw: HW | None = None) -> float:
+                    hw: HW, live_tiles: int | None = None,
+                    dtype_bytes: int = 2) -> float:
     """Live (m-tile, expert) pairs do a [bm,K]x[K,bn] MAC; dead pairs
     still pay a grid step (the tile-skip saves MXU+HBM, not issue)."""
-    hw = hw or get_hw()
     bm, bn = blocks
     n_m, n_n = M // bm, N // bn
     if live_tiles is None:

@@ -55,9 +55,11 @@ A KV tile k is skipped for Q tile q when any of these hold:
 Each rule is conservative (a skipped tile is provably all-masked for
 ANY layout, contiguous or not); contiguous packed layouts are where the
 intervals become tight and most of the grid drops out.  The mask is
-computed once on the host side of the ``pallas_call`` (O(nQ*nK), not
-O(T^2)) and read as an SMEM scalar; all three kernels wrap their tile
-body in ``pl.when(live)`` so skipped tiles issue no MXU work.
+computed once outside the ``pallas_call`` (O(nQ*nK), not O(T^2)) and
+scalar-prefetched into SMEM; all three kernels wrap their tile body in
+``pl.when(live)`` so skipped tiles issue no MXU work.  The flat mask
+holds B*nQ*nK int32 words of SMEM, which bounds the sequence length a
+call can take at a given block size.
 
 Differentiation
 ---------------
@@ -66,9 +68,8 @@ train steps flow through the Pallas dq/dk/dv kernels, never through a
 dense ``[Tq, Tkv]`` mask.  seg/pos inputs get symbolic-zero (float0)
 cotangents.
 
-``interpret=True`` runs the kernel bodies in Python/XLA on CPU (the
-validation mode for this container); on real TPU pass False to compile
-via Mosaic.
+``interpret=True`` runs the kernel bodies in Python/XLA on the CPU (the
+validation mode of the tests); on a TPU they compile through Mosaic.
 """
 from __future__ import annotations
 
@@ -167,21 +168,33 @@ def tile_skip_fraction(
 
 # ----------------------------------------------------------------------
 # Kernel bodies.
+#
+# Layouts (Mosaic wants the last two block dims (8, 128)-aligned or
+# whole): per-row values of the Q side (q seg/pos, lse, delta) travel as
+# [.., T, 1] columns and per-key values of the KV side (kv seg/pos) as
+# [.., 1, T] rows, so a tile mask is a plain [bq, 1] x [1, bk]
+# broadcast.  The live-tile mask is flattened to [B * nQ * nK] and
+# arrives by scalar prefetch (SMEM), read before the tile body runs.
 # ----------------------------------------------------------------------
 def _tile_mask(qs, ks, qp, kp, *, causal, window):
-    """[bq, bk] bool mask for one score tile."""
-    mask = (qs[:, None] == ks[None, :]) & (qs[:, None] > 0)
+    """[bq, bk] bool mask for one score tile (qs/qp [bq, 1], ks/kp
+    [1, bk])."""
+    mask = (qs == ks) & (qs > 0)
     if causal:
-        mask &= kp[None, :] <= qp[:, None]
+        mask &= kp <= qp
     if window is not None:
-        mask &= qp[:, None] - kp[None, :] < window
+        mask &= qp - kp < window
     return mask
+
+
+def _live(live_ref, b, iq, ik, n_q, n_kv):
+    return live_ref[(b * n_q + iq) * n_kv + ik] > 0
 
 
 def _fwd_kernel(live_ref, q_ref, k_ref, v_ref, qseg_ref, kseg_ref, qpos_ref,
                 kpos_ref, out_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                causal, window, scale, n_kv):
-    ik = pl.program_id(2)
+                causal, window, scale, H, n_q, n_kv):
+    b, iq, ik = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
@@ -189,7 +202,7 @@ def _fwd_kernel(live_ref, q_ref, k_ref, v_ref, qseg_ref, kseg_ref, qpos_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(live_ref[0, 0, 0] > 0)
+    @pl.when(_live(live_ref, b // H, iq, ik, n_q, n_kv))
     def _body():
         q = q_ref[0].astype(jnp.float32)  # [bq, D]
         k = k_ref[0].astype(jnp.float32)  # [bk, D]
@@ -202,53 +215,62 @@ def _fwd_kernel(live_ref, q_ref, k_ref, v_ref, qseg_ref, kseg_ref, qpos_ref,
                           causal=causal, window=window)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_scr[...]                                   # [bq, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         # Masked entries contribute exactly zero (fully-masked rows would
         # otherwise see exp(NEG_INF - NEG_INF) = 1).
-        p = jnp.exp(s - m_new[:, None]) * mask.astype(jnp.float32)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_scr[...] * corr + p.sum(axis=1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         m_scr[...] = m_new
-        l_scr[...] = l_new
 
     @pl.when(ik == n_kv - 1)
     def _finalize():
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        out_ref[0, ...] = (acc_scr[...] / l_safe[:, None]).astype(out_ref.dtype)
+        out_ref[0, ...] = (acc_scr[...] / l_safe).astype(out_ref.dtype)
         lse_ref[0, ...] = jnp.where(l > 0.0, m_scr[...] + jnp.log(l_safe), 0.0)
+
+
+def _probs_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
+                  kseg_ref, qpos_ref, kpos_ref, *, causal, window, scale):
+    """Recomputed softmax tile p and score gradient ds ([bq, bk] each),
+    plus the f32 q/k/do tiles they came from."""
+    q = q_ref[0].astype(jnp.float32)
+    k = k_ref[0].astype(jnp.float32)
+    v = v_ref[0].astype(jnp.float32)
+    do = do_ref[0].astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    mask = _tile_mask(qseg_ref[0], kseg_ref[0], qpos_ref[0], kpos_ref[0],
+                      causal=causal, window=window)
+    p = jnp.where(mask, jnp.exp(s - lse_ref[0]), 0.0)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )  # [bq, bk]
+    ds = p * (dp - delta_ref[0]) * scale
+    return q, k, do, p, ds
 
 
 def _dq_kernel(live_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                qseg_ref, kseg_ref, qpos_ref, kpos_ref, dq_ref, dq_scr, *,
-               causal, window, scale, n_kv):
-    ik = pl.program_id(2)
+               causal, window, scale, H, n_q, n_kv):
+    b, iq, ik = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    @pl.when(live_ref[0, 0, 0] > 0)
+    @pl.when(_live(live_ref, b // H, iq, ik, n_q, n_kv))
     def _body():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        mask = _tile_mask(qseg_ref[0], kseg_ref[0], qpos_ref[0], kpos_ref[0],
-                          causal=causal, window=window)
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, None]) * mask.astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, bk]
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        _, k, _, _, ds = _probs_and_ds(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
+            kseg_ref, qpos_ref, kpos_ref, causal=causal, window=window,
+            scale=scale)
         dq_scr[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -260,56 +282,41 @@ def _dq_kernel(live_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _dkv_kernel(live_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 qseg_ref, kseg_ref, qpos_ref, kpos_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, causal, window, scale, n_t):
+                dk_scr, dv_scr, *, causal, window, scale, Hkv, n_q, n_kv, n_t):
     """Grid (B*Hkv, nK, nQ * group): the innermost axis walks every
     (GQA group member, Q tile) pair, so dk/dv accumulate the full group
     sum in scratch and are emitted once per KV head -- no repeated K/V
     and no post-hoc reduction."""
-    iq = pl.program_id(2)
+    b, ik, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
-    @pl.when(iq == 0)
+    @pl.when(t == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(live_ref[0, 0, 0] > 0)
+    @pl.when(_live(live_ref, b // Hkv, t % n_q, ik, n_q, n_kv))
     def _body():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [bq, bk]
-        mask = _tile_mask(qseg_ref[0], kseg_ref[0], qpos_ref[0], kpos_ref[0],
-                          causal=causal, window=window)
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, None]) * mask.astype(jnp.float32)
+        q, _, do, p, ds = _probs_and_ds(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
+            kseg_ref, qpos_ref, kpos_ref, causal=causal, window=window,
+            scale=scale)
         dv_scr[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )  # [bk, D]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_ref[0][:, None]) * scale
         dk_scr[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(iq == n_t - 1)
+    @pl.when(t == n_t - 1)
     def _finalize():
         dk_ref[0, ...] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, ...] = dv_scr[...].astype(dv_ref.dtype)
 
 
 # ----------------------------------------------------------------------
-# pallas_call wrappers (flat [B*H, T, D] layouts).
+# pallas_call wrappers (flat [B*H, T, D] layouts; q-side rows [B, T, 1],
+# kv-side rows [B, 1, T]; index maps take the prefetched mask last).
 # ----------------------------------------------------------------------
-def _live_spec(H):
-    return pl.BlockSpec((1, 1, 1), lambda b, i, j, H=H: (b // H, i, j),
-                        memory_space=pltpu.SMEM)
-
-
 def _kv_head(b, H, Hkv):
     """Flat q index [0, B*H) -> flat kv index [0, B*Hkv) (GQA grouping:
     q head h reads kv head h // (H // Hkv), matching _gqa_* in
@@ -321,38 +328,39 @@ def _forward(qf, kf, vf, q_seg, kv_seg, q_pos, kv_pos, live, *, causal,
              window, scale, bq, bk, interpret):
     BH, Tq, D = qf.shape
     Tkv = kf.shape[1]
-    H = BH // q_seg.shape[0]
-    Hkv = kf.shape[0] // q_seg.shape[0]
+    B = q_seg.shape[0]
+    H, Hkv = BH // B, kf.shape[0] // B
     n_q, n_kv = Tq // bq, Tkv // bk
     kernel = functools.partial(
-        _fwd_kernel, causal=causal, window=window, scale=scale, n_kv=n_kv
-    )
+        _fwd_kernel, causal=causal, window=window, scale=scale, H=H,
+        n_q=n_q, n_kv=n_kv)
     kvh = functools.partial(_kv_head, H=H, Hkv=Hkv)
+    q_row = pl.BlockSpec((1, bq, 1), lambda b, iq, ik, _: (b // H, iq, 0))
+    k_row = pl.BlockSpec((1, 1, bk), lambda b, iq, ik, _: (b // H, 0, ik))
     return pl.pallas_call(
         kernel,
-        grid=(BH, n_q, n_kv),
-        in_specs=[
-            _live_spec(H),
-            pl.BlockSpec((1, bq, D), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, iq, ik: (kvh(b), ik, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, iq, ik: (kvh(b), ik, 0)),
-            pl.BlockSpec((1, bq), lambda b, iq, ik, H=H: (b // H, iq)),
-            pl.BlockSpec((1, bk), lambda b, iq, ik, H=H: (b // H, ik)),
-            pl.BlockSpec((1, bq), lambda b, iq, ik, H=H: (b // H, iq)),
-            pl.BlockSpec((1, bk), lambda b, iq, ik, H=H: (b // H, ik)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, bq), lambda b, iq, ik: (b, iq)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BH, n_q, n_kv),
+            in_specs=[
+                pl.BlockSpec((1, bq, D), lambda b, iq, ik, _: (b, iq, 0)),
+                pl.BlockSpec((1, bk, D), lambda b, iq, ik, _: (kvh(b), ik, 0)),
+                pl.BlockSpec((1, bk, D), lambda b, iq, ik, _: (kvh(b), ik, 0)),
+                q_row, k_row, q_row, k_row,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, D), lambda b, iq, ik, _: (b, iq, 0)),
+                pl.BlockSpec((1, bq, 1), lambda b, iq, ik, _: (b, iq, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),    # running max m
+                pltpu.VMEM((bq, 1), jnp.float32),    # running denom l
+                pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tq, D), qf.dtype),
-            jax.ShapeDtypeStruct((BH, Tq), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),      # running max m
-            pltpu.VMEM((bq,), jnp.float32),      # running denom l
-            pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
+            jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(live, qf, kf, vf, q_seg, kv_seg, q_pos, kv_pos)
@@ -367,74 +375,75 @@ def _backward(qf, kf, vf, dof, lse, delta, q_seg, kv_seg, q_pos, kv_pos,
     g = H // Hkv
     n_q, n_kv = Tq // bq, Tkv // bk
     kvh = functools.partial(_kv_head, H=H, Hkv=Hkv)
+    args = (live, qf, kf, vf, dof, lse, delta, q_seg, kv_seg, q_pos, kv_pos)
 
+    q_tile = pl.BlockSpec((1, bq, D), lambda b, iq, ik, _: (b, iq, 0))
+    q_col = pl.BlockSpec((1, bq, 1), lambda b, iq, ik, _: (b, iq, 0))
+    q_row = pl.BlockSpec((1, bq, 1), lambda b, iq, ik, _: (b // H, iq, 0))
+    k_row = pl.BlockSpec((1, 1, bk), lambda b, iq, ik, _: (b // H, 0, ik))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, window=window,
-                          scale=scale, n_kv=n_kv),
-        grid=(BH, n_q, n_kv),
-        in_specs=[
-            _live_spec(H),
-            pl.BlockSpec((1, bq, D), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, iq, ik: (kvh(b), ik, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, iq, ik: (kvh(b), ik, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, bq), lambda b, iq, ik: (b, iq)),
-            pl.BlockSpec((1, bq), lambda b, iq, ik: (b, iq)),
-            pl.BlockSpec((1, bq), lambda b, iq, ik, H=H: (b // H, iq)),
-            pl.BlockSpec((1, bk), lambda b, iq, ik, H=H: (b // H, ik)),
-            pl.BlockSpec((1, bq), lambda b, iq, ik, H=H: (b // H, iq)),
-            pl.BlockSpec((1, bk), lambda b, iq, ik, H=H: (b // H, ik)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, iq, ik: (b, iq, 0)),
+                          scale=scale, H=H, n_q=n_q, n_kv=n_kv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BH, n_q, n_kv),
+            in_specs=[
+                q_tile,
+                pl.BlockSpec((1, bk, D), lambda b, iq, ik, _: (kvh(b), ik, 0)),
+                pl.BlockSpec((1, bk, D), lambda b, iq, ik, _: (kvh(b), ik, 0)),
+                q_tile, q_col, q_col, q_row, k_row, q_row, k_row,
+            ],
+            out_specs=q_tile,
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((BH, Tq, D), qf.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
-    )(live, qf, kf, vf, dof, lse, delta, q_seg, kv_seg, q_pos, kv_pos)
+    )(*args)
 
     # dk/dv grid walks (group member, Q tile) pairs innermost so each KV
     # head's scratch accumulates the whole GQA group before one emit.
     def qb(b, t):
         return (b // Hkv) * H + (b % Hkv) * g + t // n_q
 
+    kv_tile = pl.BlockSpec((1, bk, D), lambda b, ik, t, _: (b, ik, 0))
+    q_tile = pl.BlockSpec((1, bq, D), lambda b, ik, t, _: (qb(b, t), t % n_q, 0))
+    q_col = pl.BlockSpec((1, bq, 1), lambda b, ik, t, _: (qb(b, t), t % n_q, 0))
+    q_row = pl.BlockSpec((1, bq, 1), lambda b, ik, t, _: (b // Hkv, t % n_q, 0))
+    k_row = pl.BlockSpec((1, 1, bk), lambda b, ik, t, _: (b // Hkv, 0, ik))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, window=window,
-                          scale=scale, n_t=n_q * g),
-        grid=(BHkv, n_kv, n_q * g),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1),
-                         lambda b, ik, t: (b // Hkv, t % n_q, ik),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, D), lambda b, ik, t: (qb(b, t), t % n_q, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, ik, t: (b, ik, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, ik, t: (b, ik, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, ik, t: (qb(b, t), t % n_q, 0)),
-            pl.BlockSpec((1, bq), lambda b, ik, t: (qb(b, t), t % n_q)),
-            pl.BlockSpec((1, bq), lambda b, ik, t: (qb(b, t), t % n_q)),
-            pl.BlockSpec((1, bq), lambda b, ik, t: (b // Hkv, t % n_q)),
-            pl.BlockSpec((1, bk), lambda b, ik, t: (b // Hkv, ik)),
-            pl.BlockSpec((1, bq), lambda b, ik, t: (b // Hkv, t % n_q)),
-            pl.BlockSpec((1, bk), lambda b, ik, t: (b // Hkv, ik)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, ik, t: (b, ik, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, ik, t: (b, ik, 0)),
-        ],
+                          scale=scale, Hkv=Hkv, n_q=n_q, n_kv=n_kv,
+                          n_t=n_q * g),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BHkv, n_kv, n_q * g),
+            in_specs=[q_tile, kv_tile, kv_tile, q_tile, q_col, q_col,
+                      q_row, k_row, q_row, k_row],
+            out_specs=[kv_tile, kv_tile],
+            scratch_shapes=[
+                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((BHkv, Tkv, D), kf.dtype),
             jax.ShapeDtypeStruct((BHkv, Tkv, D), vf.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
-        ],
         interpret=interpret,
-    )(live, qf, kf, vf, dof, lse, delta, q_seg, kv_seg, q_pos, kv_pos)
+    )(*args)
     return dq, dk, dv
 
 
 # ----------------------------------------------------------------------
 # custom_vjp assembly.
 # ----------------------------------------------------------------------
+def _rows(q_seg, kv_seg, q_pos, kv_pos):
+    """[B, T] seg/pos -> the kernels' q-side [B, Tq, 1] columns and
+    kv-side [B, 1, Tkv] rows (free reshapes)."""
+    return (q_seg[:, :, None], kv_seg[:, None, :], q_pos[:, :, None],
+            kv_pos[:, None, :])
+
+
 @functools.lru_cache(maxsize=None)
 def _make_diff_flash(causal, window, bq, bk, interpret, block_skip):
     def _prep(q, q_seg, kv_seg, q_pos, kv_pos):
@@ -447,7 +456,7 @@ def _make_diff_flash(causal, window, bq, bk, interpret, block_skip):
         else:
             live = jnp.ones(
                 (B, Tq // bq, kv_seg.shape[1] // bk), jnp.int32)
-        return scale, live
+        return scale, live.reshape(-1)
 
     def _run_fwd(q, k, v, q_seg, kv_seg, q_pos, kv_pos):
         B, H, Tq, D = q.shape
@@ -455,7 +464,7 @@ def _make_diff_flash(causal, window, bq, bk, interpret, block_skip):
         scale, live = _prep(q, q_seg, kv_seg, q_pos, kv_pos)
         out, lse = _forward(
             q.reshape(B * H, Tq, D), k.reshape(B * Hkv, Tkv, D),
-            v.reshape(B * Hkv, Tkv, D), q_seg, kv_seg, q_pos, kv_pos,
+            v.reshape(B * Hkv, Tkv, D), *_rows(q_seg, kv_seg, q_pos, kv_pos),
             live, causal=causal, window=window, scale=scale, bq=bq, bk=bk,
             interpret=interpret)
         return out.reshape(B, H, Tq, D), lse, live
@@ -476,12 +485,13 @@ def _make_diff_flash(causal, window, bq, bk, interpret, block_skip):
         scale = 1.0 / np.sqrt(D)
         dof = do.reshape(B * H, Tq, D)
         outf = out.reshape(B * H, Tq, D)
-        delta = (dof.astype(jnp.float32) * outf.astype(jnp.float32)).sum(-1)
+        delta = (dof.astype(jnp.float32) * outf.astype(jnp.float32)).sum(
+            -1, keepdims=True)  # [B*H, Tq, 1]
         dq, dk, dv = _backward(
             q.reshape(B * H, Tq, D), k.reshape(B * Hkv, Tkv, D),
-            v.reshape(B * Hkv, Tkv, D), dof, lse, delta, q_seg, kv_seg,
-            q_pos, kv_pos, live, causal=causal, window=window, scale=scale,
-            bq=bq, bk=bk, interpret=interpret)
+            v.reshape(B * Hkv, Tkv, D), dof, lse, delta,
+            *_rows(q_seg, kv_seg, q_pos, kv_pos), live, causal=causal,
+            window=window, scale=scale, bq=bq, bk=bk, interpret=interpret)
         zero = lambda a: np.zeros(a.shape, jax.dtypes.float0)
         return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
                 zero(q_seg), zero(kv_seg), zero(q_pos), zero(kv_pos))

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["flash_attention_ref", "selective_scan_ref"]
+__all__ = ["flash_attention_ref", "grouped_matmul_ref", "selective_scan_ref"]
 
 NEG_INF = -2.0**30
 
@@ -47,3 +47,18 @@ def selective_scan_ref(u, delta, A, B, C, D, seg):
     h0 = jnp.zeros((di, N), jnp.float32)
     _, ys = jax.lax.scan(step, h0, jnp.arange(T))
     return ys.astype(u.dtype)
+
+
+def grouped_matmul_ref(x, w, group_offsets):
+    """Grouped-GEMM oracle: x [M, K]; w [E, K, N]; group_offsets [E+1].
+    Row s uses w[e] for offsets[e] <= s < offsets[e+1]; rows at or past
+    offsets[E] are zero.  One dense matmul per expert (no [M, K, N]
+    gather), so it runs at real MoE widths."""
+    E = w.shape[0]
+    rows = jnp.arange(x.shape[0])[:, None]
+    out = jnp.zeros((x.shape[0], w.shape[2]), jnp.float32)
+    for e in range(E):
+        live = (rows >= group_offsets[e]) & (rows < group_offsets[e + 1])
+        out = out + jnp.where(live, jnp.dot(x.astype(jnp.float32),
+                                            w[e].astype(jnp.float32)), 0.0)
+    return out.astype(x.dtype)

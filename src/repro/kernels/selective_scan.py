@@ -47,7 +47,21 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["selective_scan"]
 
 
-def _fwd_kernel(u_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, keep_ref,
+def _row(ref, t):
+    """Row ``t`` of a [ct, w] VMEM block as a [1, w] f32 value (a
+    dynamic sublane load; value indexing would lower to dynamic_slice,
+    which Mosaic rejects)."""
+    return ref[pl.ds(t, 1), :].astype(jnp.float32)
+
+
+def _keep(ref, t, bd):
+    """keep_t as a [1, bd] bool row, for ``jnp.where`` against [N, bd]
+    values (Mosaic will not broadcast a [1, 1] operand to [N, bd] in one
+    arithmetic op)."""
+    return jnp.broadcast_to(ref[pl.ds(t, 1), :], (1, bd)) > 0
+
+
+def _fwd_kernel(u_ref, dt_ref, At_ref, B_ref, C_ref, D_ref, keep_ref,
                 y_ref, ckpt_ref, hfin_ref, h_scr, *, chunk, n_t):
     it = pl.program_id(1)
 
@@ -56,38 +70,30 @@ def _fwd_kernel(u_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, keep_ref,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     ckpt_ref[0] = h_scr[...]  # state entering this chunk (bwd residual)
-
-    u = u_ref[...].astype(jnp.float32)      # [ct, bd]
-    dt = dt_ref[...].astype(jnp.float32)    # [ct, bd]
-    A = A_ref[...].astype(jnp.float32)      # [bd, N]
-    Bm = B_ref[...].astype(jnp.float32)     # [ct, N]
-    Cm = C_ref[...].astype(jnp.float32)     # [ct, N]
+    At = At_ref[...].astype(jnp.float32)    # [N, bd]
     Dv = D_ref[...].astype(jnp.float32)     # [1, bd]
-    keep = keep_ref[...]                    # [ct, 1] int32 (bool as int)
 
-    def step(t, carry):
-        h, ys = carry
-        dA = jnp.exp(dt[t][:, None] * A)  # [bd, N]
-        h = jnp.where(keep[t, 0] > 0, h, 0.0) * dA + (
-            (dt[t] * u[t])[:, None] * Bm[t][None, :]
-        )
-        y = (h * Cm[t][None, :]).sum(axis=1) + Dv[0] * u[t]
-        return h, ys.at[t].set(y)
+    def step(t, h):
+        u, dt = _row(u_ref, t), _row(dt_ref, t)        # [1, bd]
+        keep = _keep(keep_ref, t, u.shape[1])          # [1, bd] bool
+        b = _row(B_ref, t).T                            # [N, 1]
+        c = _row(C_ref, t).T
+        h = jnp.where(keep, h, 0.0) * jnp.exp(dt * At) + b * (dt * u)
+        y_ref[pl.ds(t, 1), :] = (h * c).sum(axis=0, keepdims=True) + Dv * u
+        return h
 
-    ys0 = jnp.zeros(u.shape, jnp.float32)
-    h, ys = jax.lax.fori_loop(0, chunk, step, (h_scr[...], ys0))
+    h = jax.lax.fori_loop(0, chunk, step, h_scr[...])
     h_scr[...] = h
-    y_ref[...] = ys.astype(y_ref.dtype)
 
     @pl.when(it == n_t - 1)
     def _emit_final():
         hfin_ref[...] = h
 
 
-def _bwd_kernel(u_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, keep_ref,
+def _bwd_kernel(u_ref, dt_ref, At_ref, B_ref, C_ref, D_ref, keep_ref,
                 ckpt_ref, dy_ref, dhf_ref,
                 du_ref, ddt_ref, dB_ref, dC_ref, dA_ref, dD_ref,
-                g_scr, dA_scr, dD_scr, *, chunk, n_t):
+                g_scr, dA_scr, dD_scr, hs_scr, *, chunk, n_t):
     it = pl.program_id(1)  # 0 = LAST time chunk (index maps reverse)
 
     @pl.when(it == 0)
@@ -96,64 +102,49 @@ def _bwd_kernel(u_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, keep_ref,
         dA_scr[...] = jnp.zeros_like(dA_scr)
         dD_scr[...] = jnp.zeros_like(dD_scr)
 
-    u = u_ref[...].astype(jnp.float32)      # [ct, bd]
-    dt = dt_ref[...].astype(jnp.float32)
-    A = A_ref[...].astype(jnp.float32)      # [bd, N]
-    Bm = B_ref[...].astype(jnp.float32)     # [ct, N]
-    Cm = C_ref[...].astype(jnp.float32)
+    At = At_ref[...].astype(jnp.float32)    # [N, bd]
     Dv = D_ref[...].astype(jnp.float32)     # [1, bd]
-    keep = keep_ref[...]                    # [ct, 1]
-    h0 = ckpt_ref[0]                        # [bd, N] state entering chunk
-    dy = dy_ref[...].astype(jnp.float32)    # [ct, bd]
 
-    # Recompute the post-step states of this chunk from its checkpoint.
-    def fstep(t, carry):
-        h, posts = carry
-        dA = jnp.exp(dt[t][:, None] * A)
-        h = jnp.where(keep[t, 0] > 0, h, 0.0) * dA + (
-            (dt[t] * u[t])[:, None] * Bm[t][None, :]
-        )
-        return h, posts.at[t].set(h)
+    # Recompute the states of this chunk from its checkpoint:
+    # hs_scr[t] is the state entering step t, hs_scr[t + 1] the state
+    # after it.
+    hs_scr[0] = ckpt_ref[0]
 
-    posts0 = jnp.zeros((chunk,) + h0.shape, jnp.float32)
-    _, posts = jax.lax.fori_loop(0, chunk, fstep, (h0, posts0))
+    def fstep(t, h):
+        u, dt = _row(u_ref, t), _row(dt_ref, t)
+        keep = _keep(keep_ref, t, u.shape[1])
+        h = (jnp.where(keep, h, 0.0) * jnp.exp(dt * At)
+             + _row(B_ref, t).T * (dt * u))
+        hs_scr[t + 1] = h
+        return h
+
+    jax.lax.fori_loop(0, chunk, fstep, ckpt_ref[0])
 
     def bstep(r, carry):
-        g_nxt, dus, ddts, dBs, dCs, dAa, dDa = carry
+        g_nxt, dAa, dDa = carry
         t = chunk - 1 - r
-        h_t = posts[t]
-        h_prev = jnp.where(t > 0, posts[jnp.maximum(t - 1, 0)], h0)
-        hm = jnp.where(keep[t, 0] > 0, h_prev, 0.0)
-        dA_t = jnp.exp(dt[t][:, None] * A)
-        g = dy[t][:, None] * Cm[t][None, :] + g_nxt        # [bd, N]
-        gB = (g * Bm[t][None, :]).sum(axis=1)              # [bd]
-        dus = dus.at[t].set(dy[t] * Dv[0] + dt[t] * gB)
-        ddts = ddts.at[t].set((g * hm * A * dA_t).sum(axis=1) + u[t] * gB)
-        dAa = dAa + g * hm * dt[t][:, None] * dA_t
-        dBs = dBs.at[t].set((g * (dt[t] * u[t])[:, None]).sum(axis=0))
-        dCs = dCs.at[t].set((dy[t][:, None] * h_t).sum(axis=0))
-        dDa = dDa + dy[t] * u[t]
-        g_prev = jnp.where(keep[t, 0] > 0, dA_t * g, 0.0)
-        return g_prev, dus, ddts, dBs, dCs, dAa, dDa
+        u, dt, dy = _row(u_ref, t), _row(dt_ref, t), _row(dy_ref, t)
+        keep = _keep(keep_ref, t, u.shape[1])
+        b, c = _row(B_ref, t).T, _row(C_ref, t).T   # [N, 1]
+        h_t = hs_scr[t + 1]
+        hm = jnp.where(keep, hs_scr[t], 0.0)
+        dA_t = jnp.exp(dt * At)
+        g = c * dy + g_nxt                                  # [N, bd]
+        gB = (g * b).sum(axis=0, keepdims=True)             # [1, bd]
+        du_ref[pl.ds(t, 1), :] = dy * Dv + dt * gB
+        ddt_ref[pl.ds(t, 1), :] = (
+            (g * hm * At * dA_t).sum(axis=0, keepdims=True) + u * gB)
+        dB_ref[0, pl.ds(t, 1), :] = (g * (dt * u)).sum(
+            axis=1, keepdims=True).T
+        dC_ref[0, pl.ds(t, 1), :] = (h_t * dy).sum(axis=1, keepdims=True).T
+        dAa = dAa + g * hm * dt * dA_t
+        return jnp.where(keep, dA_t * g, 0.0), dAa, dDa + dy * u
 
-    bd, N = h0.shape
-    init = (g_scr[...],
-            jnp.zeros((chunk, bd), jnp.float32),
-            jnp.zeros((chunk, bd), jnp.float32),
-            jnp.zeros((chunk, N), jnp.float32),
-            jnp.zeros((chunk, N), jnp.float32),
-            dA_scr[...],
-            dD_scr[0])
-    g, dus, ddts, dBs, dCs, dAa, dDa = jax.lax.fori_loop(
-        0, chunk, bstep, init)
-
+    g, dAa, dDa = jax.lax.fori_loop(
+        0, chunk, bstep, (g_scr[...], dA_scr[...], dD_scr[...]))
     g_scr[...] = g
     dA_scr[...] = dAa
-    dD_scr[0] = dDa
-    du_ref[...] = dus.astype(du_ref.dtype)
-    ddt_ref[...] = ddts.astype(ddt_ref.dtype)
-    dB_ref[0] = dBs
-    dC_ref[0] = dCs
+    dD_scr[...] = dDa
 
     @pl.when(it == n_t - 1)
     def _emit():
@@ -161,9 +152,9 @@ def _bwd_kernel(u_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, keep_ref,
         dD_ref[...] = dD_scr[...]
 
 
-def _fwd_call(u, delta, A, B, C, D2, keep, *, bd, ct, interpret):
+def _fwd_call(u, delta, At, B, C, D2, keep, *, bd, ct, interpret):
     T, di = u.shape
-    N = A.shape[1]
+    N = At.shape[0]
     n_d, n_t = di // bd, T // ct
     kernel = functools.partial(_fwd_kernel, chunk=ct, n_t=n_t)
     return pl.pallas_call(
@@ -172,7 +163,7 @@ def _fwd_call(u, delta, A, B, C, D2, keep, *, bd, ct, interpret):
         in_specs=[
             pl.BlockSpec((ct, bd), lambda id_, it: (it, id_)),   # u
             pl.BlockSpec((ct, bd), lambda id_, it: (it, id_)),   # delta
-            pl.BlockSpec((bd, N), lambda id_, it: (id_, 0)),     # A
+            pl.BlockSpec((N, bd), lambda id_, it: (0, id_)),     # A^T
             pl.BlockSpec((ct, N), lambda id_, it: (it, 0)),      # B
             pl.BlockSpec((ct, N), lambda id_, it: (it, 0)),      # C
             pl.BlockSpec((1, bd), lambda id_, it: (0, id_)),     # D
@@ -180,47 +171,47 @@ def _fwd_call(u, delta, A, B, C, D2, keep, *, bd, ct, interpret):
         ],
         out_specs=[
             pl.BlockSpec((ct, bd), lambda id_, it: (it, id_)),       # y
-            pl.BlockSpec((1, bd, N), lambda id_, it: (it, id_, 0)),  # ckpt
-            pl.BlockSpec((bd, N), lambda id_, it: (id_, 0)),         # h_final
+            pl.BlockSpec((1, N, bd), lambda id_, it: (it, 0, id_)),  # ckpt
+            pl.BlockSpec((N, bd), lambda id_, it: (0, id_)),         # h_final
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((T, di), u.dtype),
-            jax.ShapeDtypeStruct((n_t, di, N), jnp.float32),
-            jax.ShapeDtypeStruct((di, N), jnp.float32),
+            jax.ShapeDtypeStruct((T, di), jnp.float32),
+            jax.ShapeDtypeStruct((n_t, N, di), jnp.float32),
+            jax.ShapeDtypeStruct((N, di), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, bd), jnp.float32)],
         interpret=interpret,
-    )(u, delta, A, B, C, D2, keep)
+    )(u, delta, At, B, C, D2, keep)
 
 
-def _bwd_call(u, delta, A, B, C, D2, keep, ckpt, dy, dhf, *, bd, ct,
+def _bwd_call(u, delta, At, B, C, D2, keep, ckpt, dy, dhf, *, bd, ct,
               interpret):
     T, di = u.shape
-    N = A.shape[1]
+    N = At.shape[0]
     n_d, n_t = di // bd, T // ct
     rev = lambda it: n_t - 1 - it  # noqa: E731 - shared reversed time index
     kernel = functools.partial(_bwd_kernel, chunk=ct, n_t=n_t)
-    du, ddt, dBp, dCp, dA, dD = pl.pallas_call(
+    du, ddt, dBp, dCp, dAt, dD = pl.pallas_call(
         kernel,
         grid=(n_d, n_t),
         in_specs=[
             pl.BlockSpec((ct, bd), lambda id_, it: (rev(it), id_)),    # u
             pl.BlockSpec((ct, bd), lambda id_, it: (rev(it), id_)),    # delta
-            pl.BlockSpec((bd, N), lambda id_, it: (id_, 0)),           # A
+            pl.BlockSpec((N, bd), lambda id_, it: (0, id_)),           # A^T
             pl.BlockSpec((ct, N), lambda id_, it: (rev(it), 0)),       # B
             pl.BlockSpec((ct, N), lambda id_, it: (rev(it), 0)),       # C
             pl.BlockSpec((1, bd), lambda id_, it: (0, id_)),           # D
             pl.BlockSpec((ct, 1), lambda id_, it: (rev(it), 0)),       # keep
-            pl.BlockSpec((1, bd, N), lambda id_, it: (rev(it), id_, 0)),
+            pl.BlockSpec((1, N, bd), lambda id_, it: (rev(it), 0, id_)),
             pl.BlockSpec((ct, bd), lambda id_, it: (rev(it), id_)),    # dy
-            pl.BlockSpec((bd, N), lambda id_, it: (id_, 0)),           # dhf
+            pl.BlockSpec((N, bd), lambda id_, it: (0, id_)),           # dhf
         ],
         out_specs=[
             pl.BlockSpec((ct, bd), lambda id_, it: (rev(it), id_)),    # du
             pl.BlockSpec((ct, bd), lambda id_, it: (rev(it), id_)),    # ddt
             pl.BlockSpec((1, ct, N), lambda id_, it: (id_, rev(it), 0)),
             pl.BlockSpec((1, ct, N), lambda id_, it: (id_, rev(it), 0)),
-            pl.BlockSpec((bd, N), lambda id_, it: (id_, 0)),           # dA
+            pl.BlockSpec((N, bd), lambda id_, it: (0, id_)),           # dA^T
             pl.BlockSpec((1, bd), lambda id_, it: (0, id_)),           # dD
         ],
         out_shape=[
@@ -228,40 +219,49 @@ def _bwd_call(u, delta, A, B, C, D2, keep, ckpt, dy, dhf, *, bd, ct,
             jax.ShapeDtypeStruct((T, di), jnp.float32),
             jax.ShapeDtypeStruct((n_d, T, N), jnp.float32),
             jax.ShapeDtypeStruct((n_d, T, N), jnp.float32),
-            jax.ShapeDtypeStruct((di, N), jnp.float32),
+            jax.ShapeDtypeStruct((N, di), jnp.float32),
             jax.ShapeDtypeStruct((1, di), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bd, N), jnp.float32),   # g carry across chunks
-            pltpu.VMEM((bd, N), jnp.float32),   # dA accumulator
-            pltpu.VMEM((1, bd), jnp.float32),   # dD accumulator
+            pltpu.VMEM((N, bd), jnp.float32),           # g carry across chunks
+            pltpu.VMEM((N, bd), jnp.float32),           # dA accumulator
+            pltpu.VMEM((1, bd), jnp.float32),           # dD accumulator
+            pltpu.VMEM((ct + 1, N, bd), jnp.float32),   # recomputed states
         ],
         interpret=interpret,
-    )(u, delta, A, B, C, D2, keep, ckpt, dy, dhf)
+    )(u, delta, At, B, C, D2, keep, ckpt, dy, dhf)
     # Per-channel-block partials -> full dB/dC reductions.
-    return du, ddt, dA, dBp.sum(axis=0), dCp.sum(axis=0), dD[0]
+    return du, ddt, dAt.T, dBp.sum(axis=0), dCp.sum(axis=0), dD[0]
 
 
 @functools.lru_cache(maxsize=None)
 def _make_diff_scan(bd, ct, interpret):
+    # The kernels take f32 operands (single rows of a packed sub-32-bit
+    # block are not addressable by a dynamic sublane load) and A
+    # transposed to [N, di], so the state is lane-dense [N, bd].
+    def _f32(*xs):
+        return tuple(x.astype(jnp.float32) for x in xs)
+
+    def _run(u, delta, A, B, C, D2, keep):
+        y, ckpt, hf = _fwd_call(*_f32(u, delta, A.T, B, C, D2), keep,
+                                bd=bd, ct=ct, interpret=interpret)
+        return y.astype(u.dtype), ckpt, hf.T
+
     @jax.custom_vjp
     def scan(u, delta, A, B, C, D2, keep):
-        y, _, hf = _fwd_call(u, delta, A, B, C, D2, keep,
-                             bd=bd, ct=ct, interpret=interpret)
+        y, _, hf = _run(u, delta, A, B, C, D2, keep)
         return y, hf
 
     def fwd(u, delta, A, B, C, D2, keep):
-        y, ckpt, hf = _fwd_call(u, delta, A, B, C, D2, keep,
-                                bd=bd, ct=ct, interpret=interpret)
+        y, ckpt, hf = _run(u, delta, A, B, C, D2, keep)
         return (y, hf), (u, delta, A, B, C, D2, keep, ckpt)
 
     def bwd(res, cts):
         u, delta, A, B, C, D2, keep, ckpt = res
         dy, dhf = cts
         du, ddt, dA, dB, dC, dD = _bwd_call(
-            u, delta, A, B, C, D2, keep, ckpt,
-            dy.astype(jnp.float32), dhf.astype(jnp.float32),
-            bd=bd, ct=ct, interpret=interpret)
+            *_f32(u, delta, A.T, B, C, D2), keep, ckpt,
+            *_f32(dy, dhf.T), bd=bd, ct=ct, interpret=interpret)
         return (du.astype(u.dtype), ddt.astype(delta.dtype),
                 dA.astype(A.dtype), dB.astype(B.dtype), dC.astype(C.dtype),
                 dD[None].astype(D2.dtype),
@@ -301,7 +301,7 @@ def selective_scan(
 
     prev = jnp.concatenate([seg[:1], seg[:-1]])
     keep = ((seg > 0) & (seg == prev)).at[0].set(False)
-    keep = keep.astype(jnp.int32)[:, None]  # [T, 1]
+    keep = keep.astype(jnp.float32)[:, None]  # [T, 1], 1.0 = carry state
     D2 = D[None, :]  # [1, di]
 
     fn = _make_diff_scan(bd, ct, bool(interpret))

@@ -104,8 +104,6 @@ def _compile_once(cfg, shape, mesh, comm_mode):
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # jax<0.5 returns [dict]
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
     return mem, cost, hlo
 
@@ -164,7 +162,9 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool, comm_mode="a2a",
         return {"arch": arch, "shape": shape_name, "mesh": mesh_name,
                 "status": "skipped", "reason": "sub-quadratic attention required"}
     mesh = make_production_mesh(multi_pod=multi_pod)
-    hw = hw or get_hw(chips=int(np.prod(list(mesh.shape.values()))))
+    # The production mesh is a v5e pod slice (launch/mesh.py).
+    hw = hw or get_hw("TPU v5 lite",
+                      chips=int(np.prod(list(mesh.shape.values()))))
     t0 = time.time()
     try:
         # Pass 1: production form (scan-over-layers) -- compile success,

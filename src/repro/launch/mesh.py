@@ -21,9 +21,22 @@ axis: every stage of one pipeline sees the same post-balanced shard.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "dp_axes_of", "dp_shards_of",
+from repro.sharding.specs import dp_axes_of, dp_shards_of
+
+__all__ = ["make_mesh", "make_production_mesh", "dp_axes_of", "dp_shards_of",
            "pp_stages_of"]
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes.  The step functions place
+    only their inputs and leave every intermediate to XLA's sharding
+    propagation; ``jax.make_mesh`` otherwise returns ``Explicit`` axes,
+    under which an unsharded intermediate meeting a sharded one is a
+    type error."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False, pp: int = 1):
@@ -36,18 +49,7 @@ def make_production_mesh(*, multi_pod: bool = False, pp: int = 1):
         shape = (2, pp, 16 // pp, 16) if multi_pod else (pp, 16 // pp, 16)
         axes = (("pod", "pp", "data", "model") if multi_pod
                 else ("pp", "data", "model"))
-    return jax.make_mesh(shape, axes)
-
-
-def dp_axes_of(mesh) -> tuple[str, ...]:
-    return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
-
-
-def dp_shards_of(mesh) -> int:
-    n = 1
-    for a in dp_axes_of(mesh):
-        n *= mesh.shape[a]
-    return n
+    return make_mesh(shape, axes)
 
 
 def pp_stages_of(mesh) -> int:

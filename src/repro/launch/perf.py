@@ -136,21 +136,16 @@ def show(row, base=None):
 
 
 def main():
-    from repro.launch.roofline import HW_PRESETS
+    from repro.launch.roofline import HW_PRESETS, get_hw
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--exp", default=None)
     ap.add_argument("--list", action="store_true")
     ap.add_argument("--out", default="experiments/perf")
     ap.add_argument("--baseline-dir", default="experiments/dryrun")
-    ap.add_argument("--hw", default=None, choices=sorted(HW_PRESETS),
-                    help="hardware preset for roofline terms (default: "
-                         "$REPRO_HW or v5e)")
+    ap.add_argument("--hw", default="TPU v5 lite", choices=sorted(HW_PRESETS),
+                    help="device kind whose peaks price the roofline terms")
     args = ap.parse_args()
-    if args.hw:
-        # run_pair -> get_hw reads the env var; setting it here also
-        # covers any nested dry-run invocations.
-        os.environ["REPRO_HW"] = args.hw
     if args.list:
         for k, (a, s, vs) in EXPERIMENTS.items():
             print(f"{k}: {a} x {s} -> {sorted(vs)}")
@@ -167,7 +162,8 @@ def main():
             base = json.loads(base_f.read_text())
         else:
             print("  (computing baseline)", flush=True)
-            base = run_pair(arch, shape, multi_pod=False)
+            base = run_pair(arch, shape, multi_pod=False,
+                            hw=get_hw(args.hw, chips=256))
             base_f.write_text(json.dumps(base, indent=1, default=str))
         # Calibrated-vs-analytic f(S) coefficients for this arch (from
         # every cached dry-run shape); a ratio far from 1x flags an
@@ -190,7 +186,7 @@ def main():
                     cfg = _variant(cfg, **spec["cfg"])
                 run_kw = spec.get("run", {})
                 row = run_pair(arch, shape, multi_pod=False, cfg_override=cfg,
-                               **run_kw)
+                               hw=get_hw(args.hw, chips=256), **run_kw)
                 row["variant"] = vname
             if coeffs and coeffs != {k: row.get(k) for k in coeffs}:
                 row.update(coeffs)

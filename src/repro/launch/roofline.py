@@ -11,58 +11,69 @@ sum the RESULT buffer sizes of every all-gather / all-reduce /
 reduce-scatter / all-to-all / collective-permute / ragged-all-to-all op
 (per-device module => per-device bytes).
 
-Hardware model: named presets in ``HW_PRESETS`` (defaults to TPU v5e --
-197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI; DCI between pods is
-slower; collectives that cross the 'pod' axis are reported separately
-via their replica-group parse when available).  ``get_hw`` resolves a
-preset by name or from the ``REPRO_HW`` env var, so roofline and
-autotuner predictions aren't silently v5e numbers on other targets.
+Hardware model: per-chip peaks in ``HW_PRESETS``, keyed by the
+``device_kind`` JAX reports.  ``get_hw(device_kind)`` looks one up and
+an unknown device is an error, never a default; ``device_hw()`` resolves
+the attached device.
 """
 from __future__ import annotations
 
 import dataclasses
-import os
 import re
 from typing import Any
 
 from repro.obs.ledger import projected_mfu, useful_flops_ratio
 
-__all__ = ["HW", "HW_PRESETS", "get_hw", "RooflineReport",
+__all__ = ["HW", "HW_PRESETS", "get_hw", "device_hw", "RooflineReport",
            "collective_bytes", "analyze"]
 
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12  # bf16 / chip
-    hbm_bw: float = 819e9  # B/s
-    ici_bw: float = 50e9  # B/s/link
-    chips: int = 256
-    name: str = "v5e"
+    peak_flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # B/s per chip
+    ici_bw: float  # B/s per link
+    name: str  # the device_kind JAX reports
+    chips: int = 1
 
 
-# Public per-chip specs (bf16 peak, HBM bandwidth, per-link ICI).
+# Per-chip peaks by ``device_kind``.  Source: Google Cloud TPU
+# documentation, the "System architecture" page of each generation
+# (bf16 peak compute, HBM bandwidth, inter-chip interconnect per link).
 HW_PRESETS: dict[str, HW] = {
-    "v4": HW(peak_flops=275e12, hbm_bw=1228e9, ici_bw=50e9, name="v4"),
-    "v5e": HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9, name="v5e"),
-    "v5p": HW(peak_flops=459e12, hbm_bw=2765e9, ici_bw=100e9, name="v5p"),
-    "v6e": HW(peak_flops=918e12, hbm_bw=1640e9, ici_bw=100e9, name="v6e"),
+    "TPU v4": HW(peak_flops=275e12, hbm_bw=1228e9, ici_bw=50e9,
+                 name="TPU v4"),
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                      name="TPU v5 lite"),
+    "TPU v5": HW(peak_flops=459e12, hbm_bw=2765e9, ici_bw=100e9,
+                 name="TPU v5"),
+    "TPU v6 lite": HW(peak_flops=918e12, hbm_bw=1640e9, ici_bw=100e9,
+                      name="TPU v6 lite"),
 }
 
 
-def get_hw(name: str | None = None, *, chips: int | None = None) -> HW:
-    """Resolve a hardware preset: explicit ``name`` > ``REPRO_HW`` env
-    var > "v5e".  ``chips`` overrides the preset's chip count (e.g. from
-    the actual mesh)."""
-    name = name or os.environ.get("REPRO_HW") or "v5e"
+def get_hw(device_kind: str, *, chips: int | None = None) -> HW:
+    """Peaks of ``device_kind`` (as ``jax.Device.device_kind`` spells
+    it); ``chips`` sets the chip count the roofline divides by.  An
+    unknown kind raises ``ValueError``."""
     try:
-        hw = HW_PRESETS[name]
+        hw = HW_PRESETS[device_kind]
     except KeyError:
         raise ValueError(
-            f"unknown HW preset {name!r}; choose from {sorted(HW_PRESETS)}"
-        ) from None
+            f"no peak table entry for device kind {device_kind!r}; known: "
+            f"{sorted(HW_PRESETS)}") from None
     if chips is not None:
         hw = dataclasses.replace(hw, chips=chips)
     return hw
+
+
+def device_hw(device=None) -> HW:
+    """Peaks of ``device`` (default: the first JAX device), with the
+    process's device count as ``chips``."""
+    import jax
+
+    device = device or jax.devices()[0]
+    return get_hw(device.device_kind, chips=len(jax.devices()))
 
 
 _DTYPE_BYTES = {
@@ -149,7 +160,7 @@ def analyze(
     hlo_text: str,
     memory: dict[str, Any],
     model_flops_global: float,
-    hw: HW = HW(),
+    hw: HW,
 ) -> RooflineReport:
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))

@@ -54,18 +54,24 @@ Resuming with a *different* ``--d`` than the checkpoint's is the elastic
 path: the global batch is re-split across the new DP degree and the
 Batch Post-Balancing Dispatcher re-solves assignments for the new shard
 count -- no divisibility requirement between old and new world sizes.
+
+Programmatic use: :func:`train` runs the loop for a given config and
+parsed arguments (``parse_args``) and returns one record per step
+(loss, grad norm, device-complete step time, programs compiled in the
+step); ``chip_smoke.py`` drives the chip bring-up through it.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import glob
 import json
 import os
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import (
     CheckpointManager,
@@ -80,6 +86,7 @@ from repro.configs import get_config
 from repro.core.orchestrator import MLLMGlobalOrchestrator
 from repro.data.pipeline import PrefetchingLoader
 from repro.data.synthetic import Example
+from repro.launch.mesh import make_mesh
 from repro.obs import (AlertBridge, AnomalyMonitor, FlightRecorder,
                        GapWaterfall, MetricsRegistry, MetricsServer,
                        StepLedger, build_timeline, render_text,
@@ -88,6 +95,7 @@ from repro.sharding.specs import opt_state_specs, param_specs, to_shardings
 from repro.telemetry import AdaptiveOrchestration
 from repro.training.optimizer import AdamWConfig
 from repro.training.train_step import init_train_state, make_train_step
+from repro.utils import CompileWatch, setup_compile_cache
 
 
 def _sampler_for(cfg):
@@ -115,7 +123,7 @@ def _sampler_for(cfg):
     return sampler
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -186,11 +194,27 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true",
                     help="resume from the newest complete checkpoint in "
                          "--ckpt-dir (elastic when --d differs)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main(argv: list[str] | None = None) -> None:
+    args = parse_args(argv)
+    setup_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
+    train(cfg, args)
+
+
+def train(cfg, args: argparse.Namespace) -> list[dict]:
+    """The training loop: ``cfg`` -> orchestrator -> PrefetchingLoader
+    -> ``make_train_step`` -> ``jax.jit``, with ``args`` as
+    :func:`parse_args` returns them (``args.arch`` and ``args.smoke``
+    are not read: ``cfg`` is the model).  Returns one record per step:
+    ``{"step", "loss", "grad_norm", "step_ms", "compiles"}``, where
+    ``step_ms`` is the device-complete step time (read with
+    ``block_until_ready`` once the next step is dispatched) and
+    ``compiles`` counts the programs JAX lowered for the step."""
     print(f"arch={cfg.name} params~{cfg.param_count()/1e6:.1f}M "
           f"family={cfg.family}")
 
@@ -203,17 +227,20 @@ def main() -> None:
     registry = ledger = recorder = alerts = None
     waterfall = monitor = ledger_monitor = server = None
     if args.metrics_dir:
-        from repro.launch.roofline import get_hw
+        from repro.launch.roofline import device_hw
 
         os.makedirs(args.metrics_dir, exist_ok=True)
         registry = MetricsRegistry()
         set_registry(registry)  # kernel hooks publish here too
-        hw = get_hw()
+        # Hardware MFU is a device metric: only a TPU has a peak (an
+        # unknown TPU kind is an error); elsewhere it is not computed.
+        hw = device_hw() if jax.default_backend() == "tpu" else None
         recorder = FlightRecorder(
             os.path.join(args.metrics_dir, "flight.jsonl"),
             meta={"arch": cfg.name, "d": args.d, "per": args.per,
                   "steps": args.steps, "adaptive": args.adaptive,
-                  "hw": hw.name, "smoke": args.smoke})
+                  "device": jax.devices()[0].device_kind,
+                  "smoke": args.smoke})
         alerts = AlertBridge(recorder, registry)
         waterfall = GapWaterfall(registry=registry)
         # Two monitors because the ledger and the waterfall both track
@@ -245,7 +272,7 @@ def main() -> None:
     dp_axes = ("data",)
     if args.mesh == "host":
         n = len(jax.devices())
-        mesh = jax.make_mesh((n, 1), ("data", "model"))
+        mesh = make_mesh((n, 1), ("data", "model"))
 
     manager = None
     if args.ckpt_dir:
@@ -311,7 +338,8 @@ def main() -> None:
 
     if registry is not None:
         ledger = StepLedger(cfg, d=cursor.d, registry=registry,
-                            peak_flops=hw.peak_flops, chips=cursor.d)
+                            peak_flops=hw.peak_flops if hw else None,
+                            chips=len(jax.devices()))
         if manager is not None:
             # A fallback restore leaves flagged *.corrupt litter behind;
             # surface each one as a structured alert.
@@ -343,7 +371,18 @@ def main() -> None:
     if mesh is not None:
         p_specs = param_specs(cfg, params, mesh)
         if not resumed_on_mesh:  # resume already placed via the manifest
-            params = jax.device_put(params, to_shardings(p_specs, mesh))
+            params, opt_state = jax.device_put(
+                (params, opt_state),
+                to_shardings((p_specs, opt_state_specs(p_specs)), mesh))
+        # Each DP shard's rows of the batch go to its own device.
+        batch_sharding = NamedSharding(mesh, P(dp_axes))
+    else:
+        # Commit the state where the step's outputs will live, so the
+        # second step sees the same argument placement as the first
+        # and reuses its executable.
+        batch_sharding = jax.devices()[0]
+        params, opt_state = jax.device_put((params, opt_state),
+                                           batch_sharding)
     step = jax.jit(step_fn, donate_argnums=(0, 1))
 
     def save_ckpt(next_step: int) -> None:
@@ -364,105 +403,133 @@ def main() -> None:
         print(f"checkpoint: step {next_step} -> {path}", flush=True)
 
     t0 = time.time()
-    done = start_step
     pending_ckpt_ms = 0.0  # save wall charged to the NEXT step's waterfall
     last_pipeline = None  # newest PipelinePlan (pp > 1): timeline lanes
-    try:
-        for it in range(start_step, args.steps):
-            batch_np, report, _ = next(loader)
-            if (args.inject_straggler is not None
-                    and it >= args.inject_straggler):
-                # Fault injection: one shard's LLM phase runs 1.6x hot,
-                # exactly the residual-imbalance signature the waterfall
-                # attributes to imbalance_llm (triage: straggler_llm).
-                costs = np.asarray(report.phase_costs["llm"],
-                                   dtype=np.float64).copy()
-                costs[0] *= 1.6
-                report.phase_costs["llm"] = costs
-            batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
-            ts = time.perf_counter()
-            params, opt_state, m = step(params, opt_state, batch)
-            step_ms = None
-            if adaptive is not None or ledger is not None:
-                # Calibration and the ledger need the device-complete
-                # step time; the sync is only paid when either is on
-                # (the default path keeps async dispatch overlap).
-                jax.block_until_ready(m["loss"])
-                step_ms = (time.perf_counter() - ts) * 1e3
-                if args.inject_drift is not None and it >= args.inject_drift:
-                    # Fault injection: pretend the step slowed 3x so the
-                    # CUSUM detector (and the alert path behind it) fire
-                    # without needing a real hardware regression.
-                    step_ms *= 3.0
-            if adaptive is not None and it > start_step:
-                # Skip the process's first step (dominated by XLA
-                # compilation -- also the first step AFTER a resume,
-                # which recompiles in the fresh process).  The
-                # whole-step time is attributed to the LLM backbone
-                # phase -- on a CPU smoke run the encoders are
-                # noise; a per-phase profiler would feed each phase.
-                drift = orch.observe_phase_times({"llm": step_ms},
-                                                 report=report, step=it)
-                if alerts is not None:
-                    alerts.on_drift(drift, step=it)
-            if ledger is not None:
-                host_m = {k: float(v) for k, v in m.items()
-                          if np.ndim(v) == 0}
-                if (args.inject_drop_spike is not None
-                        and it >= args.inject_drop_spike):
-                    # Fault injection: a capacity-overflow drop storm.
-                    host_m["moe_dropped_frac"] = 0.2
-                events = ledger.record_step(it, report=report,
-                                            step_ms=step_ms, metrics=host_m)
-                alerts.on_ledger_events(events)
-                if report.pipeline is not None:
-                    # Per-stage bubble series + fill/uplift gauges; the
-                    # waterfall below picks the plan off the report and
-                    # switches to its pipeline_bubble_s{k} algebra.
-                    ledger.record_pipeline(it, report.pipeline)
-                    last_pipeline = report.pipeline
-                # The smoke path runs dense reference attention, so the
-                # tile fraction the Pallas kernels would have skipped IS
-                # dead compute actually paid this step -- but only for
-                # the attention share of the step's FLOPs, so weight it
-                # down before charging it against total useful compute.
-                dead = ledger.series.get("kernel_flash_skip_frac")
-                attn_share = 0.2
-                if it > start_step:
-                    # Skip the compile-dominated first step: its wall
-                    # time would poison the waterfall's cost->ms EWMA
-                    # (same reason the calibrator skips it above).
-                    wf = waterfall.observe(
-                        it, report=report, step_ms=step_ms, metrics=host_m,
-                        ckpt_ms=pending_ckpt_ms,
-                        dead_tile_frac=(dead[-1][1] * attn_share
-                                        if dead else 0.0))
-                    recorder.record("waterfall", **wf.to_dict())
-                pending_ckpt_ms = 0.0
-                monitor.poll(waterfall.series)
-                ledger_monitor.poll(ledger.series)
-                if (it - start_step) % max(args.metrics_every, 1) == 0:
-                    ledger.record_kernel_stats(it, batch_np)
-                    write_openmetrics(
-                        os.path.join(args.metrics_dir, "metrics.prom"),
-                        registry)
-                    recorder.record("flush", step=it,
-                                    **{k: v for k, v in ledger.summary().items()
-                                       if isinstance(v, (int, float))})
-                    recorder.flush()
-            done = it + 1
-            if manager is not None and args.ckpt_every > 0 \
-                    and done % args.ckpt_every == 0 and done < args.steps:
-                save_ckpt(done)
-                pending_ckpt_ms = manager.last_op_ms
-            if it % 5 == 0 or it == args.steps - 1:
-                denom = max(it + 1 - start_step, 1)
-                print(f"step {it:4d} loss={float(m['loss']):.4f} "
-                      f"gnorm={float(m['grad_norm']):.2f} "
-                      f"util={report.phase_utilization['llm']:.2f} "
-                      f"{(time.time()-t0)/denom:.2f}s/step", flush=True)
-    finally:
-        loader.close()
+    last_done = None  # host clock when the previous step was seen complete
+    records: list[dict] = []
+
+    def settle(it, m, report, batch_np, t_dispatch, compiles) -> None:
+        """Wait for step ``it`` and book it.  Called once the NEXT step
+        is dispatched (or before a checkpoint), so the device never
+        idles on the host's bookkeeping.  ``step_ms`` runs from the
+        later of the step's dispatch and the previous step's completion
+        to its own completion: device-complete, not host-dispatch,
+        time."""
+        nonlocal last_done, pending_ckpt_ms, last_pipeline
+        jax.block_until_ready(m)
+        now = time.perf_counter()
+        step_ms = (now - max(t_dispatch, last_done or t_dispatch)) * 1e3
+        last_done = now
+        records.append({"step": it, "loss": float(m["loss"]),
+                        "grad_norm": float(m["grad_norm"]),
+                        "step_ms": step_ms, "compiles": compiles})
+        if args.inject_drift is not None and it >= args.inject_drift:
+            # Fault injection: pretend the step slowed 3x so the
+            # CUSUM detector (and the alert path behind it) fire
+            # without needing a real hardware regression.
+            step_ms *= 3.0
+        if adaptive is not None and it > start_step:
+            # Skip the process's first step (dominated by XLA
+            # compilation -- also the first step AFTER a resume,
+            # which recompiles in the fresh process).  The
+            # whole-step time is attributed to the LLM backbone
+            # phase -- on a CPU smoke run the encoders are
+            # noise; a per-phase profiler would feed each phase.
+            drift = orch.observe_phase_times({"llm": step_ms},
+                                             report=report, step=it)
+            if alerts is not None:
+                alerts.on_drift(drift, step=it)
+        if ledger is not None:
+            host_m = {k: float(v) for k, v in m.items()
+                      if np.ndim(v) == 0}
+            if (args.inject_drop_spike is not None
+                    and it >= args.inject_drop_spike):
+                # Fault injection: a capacity-overflow drop storm.
+                host_m["moe_dropped_frac"] = 0.2
+            events = ledger.record_step(it, report=report,
+                                        step_ms=step_ms, metrics=host_m)
+            alerts.on_ledger_events(events)
+            if report.pipeline is not None:
+                # Per-stage bubble series + fill/uplift gauges; the
+                # waterfall below picks the plan off the report and
+                # switches to its pipeline_bubble_s{k} algebra.
+                ledger.record_pipeline(it, report.pipeline)
+                last_pipeline = report.pipeline
+            # The smoke path runs dense reference attention, so the
+            # tile fraction the Pallas kernels would have skipped IS
+            # dead compute actually paid this step -- but only for
+            # the attention share of the step's FLOPs, so weight it
+            # down before charging it against total useful compute.
+            dead = ledger.series.get("kernel_flash_skip_frac")
+            attn_share = 0.2
+            if it > start_step:
+                # Skip the compile-dominated first step: its wall
+                # time would poison the waterfall's cost->ms EWMA
+                # (same reason the calibrator skips it above).
+                wf = waterfall.observe(
+                    it, report=report, step_ms=step_ms, metrics=host_m,
+                    ckpt_ms=pending_ckpt_ms,
+                    dead_tile_frac=(dead[-1][1] * attn_share
+                                    if dead else 0.0))
+                recorder.record("waterfall", **wf.to_dict())
+            pending_ckpt_ms = 0.0
+            monitor.poll(waterfall.series)
+            ledger_monitor.poll(ledger.series)
+            if (it - start_step) % max(args.metrics_every, 1) == 0:
+                ledger.record_kernel_stats(it, batch_np)
+                write_openmetrics(
+                    os.path.join(args.metrics_dir, "metrics.prom"),
+                    registry)
+                recorder.record("flush", step=it,
+                                **{k: v for k, v in ledger.summary().items()
+                                   if isinstance(v, (int, float))})
+                recorder.flush()
+        if it % 5 == 0 or it == args.steps - 1:
+            denom = max(it + 1 - start_step, 1)
+            print(f"step {it:4d} loss={records[-1]['loss']:.4f} "
+                  f"gnorm={records[-1]['grad_norm']:.2f} "
+                  f"util={report.phase_utilization['llm']:.2f} "
+                  f"{(time.time()-t0)/denom:.2f}s/step", flush=True)
+
+    done = start_step
+    unsettled = None  # settle() arguments of the step in flight
+    # The step traces under the mesh, so Pallas kernels run per DP shard
+    # (kernels.ops.per_dp_shard).  jax.set_mesh applies the mesh when it
+    # is built and restores the previous one on exit: one for the loop.
+    with (jax.set_mesh(mesh) if mesh is not None
+          else contextlib.nullcontext()):
+        try:
+            for it in range(start_step, args.steps):
+                batch_np, report, _ = next(loader)
+                if (args.inject_straggler is not None
+                        and it >= args.inject_straggler):
+                    # Fault injection: one shard's LLM phase runs 1.6x
+                    # hot, exactly the residual-imbalance signature the
+                    # waterfall attributes to imbalance_llm (triage:
+                    # straggler_llm).
+                    costs = np.asarray(report.phase_costs["llm"],
+                                       dtype=np.float64).copy()
+                    costs[0] *= 1.6
+                    report.phase_costs["llm"] = costs
+                batch = jax.device_put(batch_np, batch_sharding)
+                with CompileWatch() as watch:
+                    t_dispatch = time.perf_counter()
+                    params, opt_state, m = step(params, opt_state, batch)
+                if unsettled is not None:
+                    settle(*unsettled)
+                unsettled = (it, m, report, batch_np, t_dispatch,
+                             watch.lowered)
+                done = it + 1
+                if manager is not None and args.ckpt_every > 0 \
+                        and done % args.ckpt_every == 0 and done < args.steps:
+                    settle(*unsettled)
+                    unsettled = None
+                    save_ckpt(done)
+                    pending_ckpt_ms = manager.last_op_ms
+            if unsettled is not None:
+                settle(*unsettled)
+        finally:
+            loader.close()
     if manager is not None and done > start_step:
         save_ckpt(done)
     if adaptive is not None:
@@ -507,6 +574,7 @@ def main() -> None:
             time.sleep(args.serve_metrics_linger)
         server.stop()
     print("training loop complete")
+    return records
 
 
 if __name__ == "__main__":

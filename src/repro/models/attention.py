@@ -30,6 +30,8 @@ caller), causal & bidirectional, and cross-attention (whisper decoder).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -351,7 +353,7 @@ def _windowed(q, k, v, q_seg, kv_seg, q_pos, kv_pos, *, causal, window,
 # ----------------------------------------------------------------------
 def _pallas_flash(q, k, v, q_seg, kv_seg, q_pos, kv_pos, *, causal, window,
                   block_q, block_kv, interpret):
-    from repro.kernels.ops import flash_attention_op
+    from repro.kernels.ops import flash_attention_op, per_dp_shard
     from repro.utils import round_up
 
     B, Tq, H, D = q.shape
@@ -369,15 +371,16 @@ def _pallas_flash(q, k, v, q_seg, kv_seg, q_pos, kv_pos, *, causal, window,
     qt = jnp.moveaxis(padt(q, pad_q), 1, 2)  # [B,H,Tq',D]
     kt = jnp.moveaxis(padt(k, pad_k), 1, 2)
     vt = jnp.moveaxis(padt(v, pad_k), 1, 2)
-    out = flash_attention_op(
-        qt, kt, vt,
+    kernel = functools.partial(
+        flash_attention_op, causal=causal,
+        window=None if window is None else int(window),
+        block_q=bq, block_kv=bk, interpret=interpret)
+    out = per_dp_shard(
+        kernel, qt, kt, vt,
         padt(q_seg.astype(jnp.int32), pad_q),
         padt(kv_seg.astype(jnp.int32), pad_k),
         padt(q_pos.astype(jnp.int32), pad_q),
-        padt(kv_pos.astype(jnp.int32), pad_k),
-        causal=causal, window=None if window is None else int(window),
-        block_q=bq, block_kv=bk, interpret=interpret,
-    )
+        padt(kv_pos.astype(jnp.int32), pad_k))
     return jnp.moveaxis(out, 1, 2)[:, :Tq]
 
 

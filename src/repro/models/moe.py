@@ -37,6 +37,8 @@ axis).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -98,28 +100,29 @@ def moe_ffn(
     flat_e = gate_ids.reshape(-1)  # [n*k]
     if valid is not None:
         flat_e = jnp.where(jnp.repeat(valid.reshape(n), top_k), flat_e, E)
-    flat_tok = jnp.repeat(jnp.arange(n), top_k)
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    sorted_tok = flat_tok[order]
 
     counts = jnp.zeros(E + 1, jnp.int32).at[flat_e].add(1)
     n_routed = jnp.maximum(counts[:E].sum(), 1)
     expert_load = counts[:E].astype(jnp.float32) / n_routed.astype(jnp.float32)
 
     if backend == "grouped":
-        expert_out, dropped = _grouped_dispatch(
-            xf, w_gate, w_up, w_down, sorted_tok, counts, n, top_k,
-            block_m=block_m, block_n=block_n, interpret=interpret)
+        # Sort, expert matmuls and combine run per DP shard: XLA cannot
+        # partition the Mosaic kernel, and drop-free routing leaves each
+        # token's output independent of every other shard's tokens.
+        from repro.kernels.ops import per_dp_shard
+
+        combined = per_dp_shard(
+            functools.partial(_grouped_ffn, top_k=top_k, block_m=block_m,
+                              block_n=block_n, interpret=interpret),
+            x, flat_e.reshape(B, T * top_k), gate_vals.reshape(B, T, top_k),
+            replicated=(w_gate, w_up, w_down))
+        dropped = jnp.int32(0)
     else:
+        order, sorted_e, sorted_tok = _sort_assignments(flat_e, top_k)
         expert_out, dropped = _dense_dispatch(
             xf, w_gate, w_up, w_down, sorted_e, sorted_tok, order, counts,
             n, top_k, E, capacity_factor, shard_buffers)
-
-    inv = jnp.argsort(order, stable=True)
-    expert_out = expert_out[inv].reshape(n, top_k, d)
-    combined = jnp.einsum("nkd,nk->nd", expert_out.astype(jnp.float32),
-                          gate_vals.astype(jnp.float32))
+        combined = _combine(expert_out, order, gate_vals)
 
     aux = {
         "lb_loss": router_load_balance_loss(
@@ -129,6 +132,24 @@ def moe_ffn(
         "dropped_frac": dropped.astype(jnp.float32) / n_routed.astype(jnp.float32),
     }
     return combined.reshape(B, T, d).astype(x.dtype), aux
+
+
+def _sort_assignments(flat_e, top_k):
+    """Stable sort of the [n*k] assignments by expert: ``(order,
+    sorted experts, sorted token ids)``."""
+    flat_tok = jnp.repeat(jnp.arange(flat_e.shape[0] // top_k), top_k)
+    order = jnp.argsort(flat_e, stable=True)
+    return order, flat_e[order], flat_tok[order]
+
+
+def _combine(expert_out, order, gate_vals):
+    """Un-sort the [n*k, d] expert outputs and weight each token's k
+    outputs by its gates: [n, d] in f32."""
+    n, k = gate_vals.shape
+    inv = jnp.argsort(order, stable=True)
+    out = expert_out[inv].reshape(n, k, -1)
+    return jnp.einsum("nkd,nk->nd", out.astype(jnp.float32),
+                      gate_vals.astype(jnp.float32))
 
 
 def _dense_dispatch(xf, w_gate, w_up, w_down, sorted_e, sorted_tok, order,
@@ -164,17 +185,22 @@ def _dense_dispatch(xf, w_gate, w_up, w_down, sorted_e, sorted_tok, order,
     return out_buf[slot], dropped  # dropped slot -> zeros row
 
 
-def _grouped_dispatch(xf, w_gate, w_up, w_down, sorted_tok, counts, n,
-                      top_k, *, block_m, block_n, interpret):
-    """Drop-free grouped-GEMM path.  Returns outputs in SORTED
-    assignment order [n*k, d]; never drops (dropped count = 0)."""
+def _grouped_ffn(x, flat_e, gate_vals, w_gate, w_up, w_down, *, top_k,
+                 block_m, block_n, interpret):
+    """Drop-free grouped-GEMM expert FFN over the tokens of ``x`` [b, T,
+    d] with their assignments ``flat_e`` [b, T*k] and gates [b, T, k]:
+    sort by expert, three grouped matmuls, combine.  Returns [b, T, d]
+    in f32."""
     from repro.kernels.ops import grouped_matmul_op
 
-    d = xf.shape[1]
+    b, T, d = x.shape
+    flat_e = flat_e.reshape(-1)
+    order, _, sorted_tok = _sort_assignments(flat_e, top_k)
+    counts = jnp.zeros(w_gate.shape[0] + 1, jnp.int32).at[flat_e].add(1)
     offsets = jnp.concatenate(
         [jnp.zeros(1, jnp.int32), jnp.cumsum(counts[:-1]).astype(jnp.int32)])
-    xs = xf[sorted_tok]  # [n*k, d] sorted by expert; sentinel rows last
-    M = n * top_k
+    xs = x.reshape(b * T, d)[sorted_tok]  # sorted by expert; sentinel last
+    M = b * T * top_k
     bm = min(block_m, M)
     pad = (-M) % bm
     if pad:
@@ -190,9 +216,8 @@ def _grouped_dispatch(xf, w_gate, w_up, w_down, sorted_tok, counts, n,
     out = grouped_matmul_op(h, w_down, offsets, block_m=bm,
                             block_n=_divisor_block(d, block_n),
                             interpret=interpret)
-    if pad:
-        out = out[:M]
-    return out, jnp.int32(0)
+    combined = _combine(out[:M], order, gate_vals.reshape(b * T, top_k))
+    return combined.reshape(b, T, d)
 
 
 def _divisor_block(size: int, target: int) -> int:

@@ -173,6 +173,17 @@ def mamba2_scan(x, delta, A_log, B, C, D, seg, *, chunk: int = 256, h0=None,
 # Full blocks (projections + conv + scan + gate), matching param layout
 # in repro.models.model.
 # ----------------------------------------------------------------------
+def _per_stream_scan(backend, scan, streams, weights):
+    """``scan(*streams, *weights)`` over [B, ...] streams.  The Pallas
+    kernel runs per DP shard (XLA cannot partition a Mosaic kernel); the
+    lax.scan backend is left to XLA's sharding propagation."""
+    if backend == "pallas":
+        from repro.kernels.ops import per_dp_shard
+
+        return per_dp_shard(scan, *streams, replicated=weights)
+    return scan(*streams, *weights)
+
+
 def mamba1_block(p, x, seg, *, ssm_state: int, chunk: int = 256,
                  backend: str = "scan", block_d: int = 128):
     """x [B,T,d] -> [B,T,d].  p: dict of this block's params."""
@@ -186,12 +197,15 @@ def mamba1_block(p, x, seg, *, ssm_state: int, chunk: int = 256,
     delta = jax.nn.softplus(jnp.einsum("btr,re->bte", dt, p["dt_proj"]) + p["dt_bias"])
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
 
-    def one(u_s, delta_s, B_s, C_s, seg_s):
-        y, _ = mamba1_scan(u_s, delta_s, A, B_s, C_s, p["D"], seg_s,
-                           chunk=chunk, backend=backend, block_d=block_d)
-        return y
+    def scan(u, delta, Bm, Cm, seg, A, D):
+        def one(u_s, delta_s, B_s, C_s, seg_s):
+            return mamba1_scan(u_s, delta_s, A, B_s, C_s, D, seg_s,
+                               chunk=chunk, backend=backend,
+                               block_d=block_d)[0]
+        return jax.vmap(one)(u, delta, Bm, Cm, seg)
 
-    y = jax.vmap(one)(xi, delta, Bm, Cm, seg)
+    y = _per_stream_scan(backend, scan, (xi, delta, Bm, Cm, seg),
+                         (A, p["D"]))
     y = y * jax.nn.silu(z)
     return jnp.einsum("bte,ed->btd", y, p["out_proj"])
 
@@ -210,12 +224,15 @@ def mamba2_block(p, x, seg, *, ssm_state: int, headdim: int, chunk: int = 256,
     delta = jax.nn.softplus(dt + p["dt_bias"])  # [B,T,H]
     xh = xi.reshape(xi.shape[0], xi.shape[1], H, headdim)
 
-    def one(x_s, delta_s, B_s, C_s, seg_s):
-        y, _ = mamba2_scan(x_s, delta_s, p["A_log"], B_s, C_s, p["D"], seg_s,
-                           chunk=chunk, backend=backend, block_d=block_d)
-        return y
+    def scan(x, delta, Bm, Cm, seg, A_log, D):
+        def one(x_s, delta_s, B_s, C_s, seg_s):
+            return mamba2_scan(x_s, delta_s, A_log, B_s, C_s, D, seg_s,
+                               chunk=chunk, backend=backend,
+                               block_d=block_d)[0]
+        return jax.vmap(one)(x, delta, Bm, Cm, seg)
 
-    y = jax.vmap(one)(xh, delta, Bm, Cm, seg)
+    y = _per_stream_scan(backend, scan, (xh, delta, Bm, Cm, seg),
+                         (p["A_log"], p["D"]))
     y = y.reshape(x.shape[0], x.shape[1], di)
     y = y * jax.nn.silu(z)
     return jnp.einsum("bte,ed->btd", y, p["out_proj"])

@@ -31,6 +31,8 @@ __all__ = [
     "opt_state_specs",
     "batch_specs",
     "cache_sharding_specs",
+    "dp_axes_of",
+    "dp_shards_of",
     "stage_partition",
     "to_shardings",
 ]
@@ -144,6 +146,19 @@ def opt_state_specs(p_specs):
         "nu": p_specs,
         "step": P(),
     }
+
+
+def dp_axes_of(mesh) -> tuple[str, ...]:
+    """The mesh's DP-shard axes, ("pod", "data") where present; works on
+    a ``Mesh`` and on an ``AbstractMesh``."""
+    return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
+
+
+def dp_shards_of(mesh) -> int:
+    n = 1
+    for a in dp_axes_of(mesh):
+        n *= mesh.shape[a]
+    return n
 
 
 def batch_specs(batch: dict[str, Any], dp_axes: tuple[str, ...]) -> dict[str, P]:

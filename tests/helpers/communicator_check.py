@@ -18,6 +18,7 @@ from repro.core.balancing import post_balance
 from repro.core.communicator import apply_comm_plan, build_comm_plan, plan_to_device
 from repro.core.cost_model import CostModel
 from repro.core.nodewise import nodewise_rearrange
+from repro.launch.mesh import make_mesh
 
 
 def reference_exchange(pi, x_global, cap_in, cap_out, feat):
@@ -80,10 +81,6 @@ def run_case(mesh, dp_axes, d, seed, mode, nodewise=False):
 def check_ragged_lowers(mesh, dp_axes, d, seed):
     """ragged_all_to_all does not execute on XLA:CPU; assert it traces
     and lowers (the TPU-target path)."""
-    if not hasattr(jax.lax, "ragged_all_to_all"):
-        print(f"skip ragged lowering: jax {jax.__version__} lacks "
-              "jax.lax.ragged_all_to_all")
-        return True
     rng = np.random.default_rng(seed)
     lens = [rng.integers(1, 40, size=3) for _ in range(d)]
     pi = post_balance(lens, d, CostModel())
@@ -106,14 +103,14 @@ def main():
     assert n_dev == 8, f"expected 8 host devices, got {n_dev}"
     ok = True
     # Flat DP mesh.
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     for mode in ("a2a", "allgather", "gather"):
         for seed in (0, 1, 2):
             ok &= run_case(mesh, ("data",), 8, seed, mode)
     ok &= run_case(mesh, ("data",), 8, 3, "a2a", nodewise=True)
     ok &= check_ragged_lowers(mesh, ("data",), 8, 5)
     # Multi-pod style mesh: DP spans ("pod", "data").
-    mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh2 = make_mesh((2, 4), ("pod", "data"))
     for mode in ("a2a", "gather"):
         ok &= run_case(mesh2, ("pod", "data"), 8, 4, mode)
     sys.exit(0 if ok else 1)

@@ -11,6 +11,7 @@ import sys
 import jax
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.launch.roofline import collective_bytes
 from repro.sharding.specs import (
     batch_specs,
@@ -93,10 +94,10 @@ def run(arch, kind, multi_pod):
 
     cfg = get_config(arch).smoke()
     if multi_pod:
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         dp_axes = ("pod", "data")
     else:
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         dp_axes = ("data",)
     dp = 4
     specs = tiny_specs(cfg, kind, dp)
@@ -119,8 +120,6 @@ def run(arch, kind, multi_pod):
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # jax<0.5 returns [dict]
-            cost = cost[0] if cost else {}
         coll = collective_bytes(compiled.as_text())
     assert cost.get("flops", 0) > 0
     assert mem.temp_size_in_bytes >= 0

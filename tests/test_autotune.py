@@ -3,6 +3,7 @@ override > cache > default), prediction-pruned measurement sweeps, and
 the roofline predictors' block sensitivity."""
 import json
 
+import jax
 import pytest
 
 from repro.kernels import autotune
@@ -101,7 +102,21 @@ def test_corrupt_cache_is_ignored(cache):
                       cache_path=cache)
     with open(cache) as f:
         data = json.load(f)
-    assert data[autotune.cache_key("flash", {"T": 1})]["blocks"] == [4, 4]
+    kind = jax.devices()[0].device_kind
+    assert data[autotune.cache_key("flash", {"T": 1, "device": kind})][
+        "blocks"] == [4, 4]
+
+
+def test_cached_winner_is_keyed_by_device_kind(cache):
+    """A winner recorded for another device kind is never applied here,
+    and the default cache lives inside the checkout."""
+    with open(cache, "w") as f:
+        json.dump({autotune.cache_key("flash", {"T": 1, "device": "TPU v4"}):
+                   {"blocks": [4, 4]}}, f)
+    assert autotune.resolve("flash", {"T": 1}, (8, 8), cache_path=cache) == (8, 8)
+    from repro.utils import CHECKOUT_CACHE
+
+    assert autotune.default_cache_path().startswith(str(CHECKOUT_CACHE))
 
 
 def test_candidate_enumerators_respect_divisibility():
@@ -117,7 +132,7 @@ def test_candidate_enumerators_respect_divisibility():
 def test_predictors_penalize_tiny_blocks():
     """Same FLOPs, more grid steps: the step-overhead term must make an
     explosion of tiny tiles strictly slower in every predictor."""
-    hw = get_hw("v5e")
+    hw = get_hw("TPU v5 lite")
     assert autotune.predict_scan((16, 16), T=4096, di=4096, N=16, hw=hw) > \
         autotune.predict_scan((128, 256), T=4096, di=4096, N=16, hw=hw)
     assert autotune.predict_flash(
@@ -133,7 +148,7 @@ def test_predictors_penalize_tiny_blocks():
 def test_predict_grouped_rewards_tile_skip():
     """Fewer live tiles (balanced routing over many experts) must
     predict faster than a dense sweep at the same shape."""
-    hw = get_hw("v5e")
+    hw = get_hw("TPU v5 lite")
     dense = autotune.predict_grouped((128, 128), M=4096, K=512, N=512, E=8,
                                      live_tiles=4096 // 128 * 8, hw=hw)
     skip = autotune.predict_grouped((128, 128), M=4096, K=512, N=512, E=8,

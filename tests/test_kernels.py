@@ -289,8 +289,15 @@ def test_grouped_matmul_vjp_matches_oracle_autodiff():
         x, w, o, block_m=64, block_n=32, interpret=True)
     (dx, dw) = make_loss(kernel_fn)(x, w)
     (dx_ref, dw_ref) = make_loss(_grouped_oracle)(x, w)
-    np.testing.assert_allclose(np.asarray(dx), np.asarray(dx_ref),
-                               atol=2e-5, rtol=2e-5, err_msg="dx")
+    # dx sums N=96 f32 products whose partial sums reach |dx| ~ 17; the
+    # kernel and the oracle add them in different orders, so an entry
+    # that cancels to near zero carries rounding error of the size of
+    # the largest entries (3.5e-5 seen), not of its own.  Scale the
+    # absolute tolerance by the array's magnitude (~6 f32 ulps of max).
+    dx_ref = np.asarray(dx_ref)
+    np.testing.assert_allclose(np.asarray(dx), dx_ref,
+                               atol=2e-5 * max(1.0, np.abs(dx_ref).max()),
+                               rtol=2e-5, err_msg="dx")
     np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_ref),
                                atol=2e-4, rtol=2e-4, err_msg="dw")
     # Empty expert and padding rows get exactly zero gradient.
@@ -494,3 +501,73 @@ def test_flash_attention_segment_isolation():
     np.testing.assert_allclose(outs[0][:, :, half:], outs[1][:, :, half:],
                                atol=1e-5)
     assert not np.allclose(outs[0][:, :, :half], outs[1][:, :, :half])
+
+
+# ----------------------------------------------------------------------
+# The Mosaic layouts at the shapes the chip runs: GQA with kv-side seg
+# rows / q-side columns and a scalar-prefetched live mask (flash), f32
+# row-by-row recurrences over a lane-dense [N, bd] state (scan).
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("bq,bk", [(256, 256), (128, 256), (256, 128)])
+def test_flash_gqa_mosaic_layout_matches_ref(bq, bk):
+    """Head dim 128, GQA group 4, unequal q/kv blocks and several packed
+    segments: the forward and all three gradients match the oracle."""
+    rng = np.random.default_rng(20)
+    B, H, Hkv, T, D = 2, 8, 2, 512, 128
+    q = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, Hkv, T, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, Hkv, T, D)), jnp.float32)
+    seg, pos = _segs(rng, B, T, 5)
+    rep = lambda x: jnp.repeat(x, H // Hkv, axis=1)  # noqa: E731
+
+    def flash(q, k, v):
+        return flash_attention_op(q, k, v, seg, seg, pos, pos, block_q=bq,
+                                  block_kv=bk, interpret=True)
+
+    def oracle(q, k, v):
+        return flash_attention_ref(q, rep(k), rep(v), seg, seg, pos, pos)
+
+    do = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.float32)
+    got, vjp = jax.vjp(flash, q, k, v)
+    want, vjp_ref = jax.vjp(oracle, q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    for name, g, w in zip("qkv", vjp(do), vjp_ref(do)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5,
+                                   rtol=5e-5, err_msg=f"d{name}")
+
+
+def test_selective_scan_bf16_inputs_run_in_f32():
+    """bf16 operands are widened to f32 before the kernel (single rows of
+    a packed bf16 block are not addressable on the chip): the result
+    equals the kernel on the widened inputs, rounded once to bf16, and
+    the state comes back as [di, N]."""
+    rng = np.random.default_rng(21)
+    T, di, N = 128, 256, 16
+    u, delta, A, B, C, D, seg = _scan_inputs(rng, T, di, N)
+    bf = lambda x: x.astype(jnp.bfloat16)  # noqa: E731
+    y16, h16 = selective_scan_op(bf(u), bf(delta), A, bf(B), bf(C), D, seg,
+                                 block_d=128, chunk=64, interpret=True,
+                                 return_state=True)
+    f32 = lambda x: bf(x).astype(jnp.float32)  # noqa: E731
+    y32, h32 = selective_scan_op(f32(u), f32(delta), A, f32(B), f32(C), D,
+                                 seg, block_d=128, chunk=64, interpret=True,
+                                 return_state=True)
+    assert y16.dtype == jnp.bfloat16 and h16.shape == (di, N)
+    np.testing.assert_array_equal(np.asarray(y16), np.asarray(bf(y32)))
+    np.testing.assert_array_equal(np.asarray(h16), np.asarray(h32))
+
+
+def test_grouped_matmul_ref_matches_gather_oracle():
+    """kernels/ref.grouped_matmul_ref (one matmul per expert, what the
+    chip smoke compares against) equals the per-row gather oracle."""
+    from repro.kernels.ref import grouped_matmul_ref
+
+    rng = np.random.default_rng(22)
+    M, K, N, E = 256, 64, 96, 4
+    x = jnp.asarray(rng.normal(size=(M, K)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(E, K, N)), jnp.float32)
+    _, offs = _group_layout(rng, M, E, empty=(1,), pad=19)
+    np.testing.assert_allclose(np.asarray(grouped_matmul_ref(x, w, offs)),
+                               np.asarray(_grouped_oracle(x, w, offs)),
+                               atol=1e-4, rtol=1e-5)

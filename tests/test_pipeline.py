@@ -334,10 +334,10 @@ def test_mesh_pp_validation():
 
 def test_mesh_pp_axes_abstract():
     from jax.sharding import AbstractMesh
-    mesh = AbstractMesh((("pp", 4), ("data", 4), ("model", 16)))
+    mesh = AbstractMesh((4, 4, 16), ("pp", "data", "model"))
     assert pp_stages_of(mesh) == 4
     assert dp_shards_of(mesh) == 4  # pp is NOT a DP axis
-    flat = AbstractMesh((("data", 16), ("model", 16)))
+    flat = AbstractMesh((16, 16), ("data", "model"))
     assert pp_stages_of(flat) == 1
     assert dp_shards_of(flat) == 16
 
@@ -352,7 +352,7 @@ def test_param_specs_pp_shards_layer_dim():
     cfg = _cfg().smoke()  # n_layers=2 -> divisible by pp=2
     params_shape = jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0)))
-    mesh = AbstractMesh((("pp", 2), ("data", 2), ("model", 2)))
+    mesh = AbstractMesh((2, 2, 2), ("pp", "data", "model"))
     specs = param_specs(cfg, params_shape, mesh)
 
     def leaves(tree, stacked=False):
@@ -371,7 +371,7 @@ def test_param_specs_pp_shards_layer_dim():
             assert "pp" not in parts  # only stacked layer dims shard on pp
     assert saw_pp
     # pp=1 mesh: unchanged legacy specs (no pp axis anywhere).
-    flat = AbstractMesh((("data", 2), ("model", 2)))
+    flat = AbstractMesh((2, 2), ("data", "model"))
     for _, spec in leaves(param_specs(cfg, params_shape, flat)):
         assert "pp" not in tuple(spec)
     assert isinstance(P(), P)  # silence unused-import pedantry

@@ -1,8 +1,10 @@
 """Roofline extraction tests: HLO collective parsing + term analysis +
 the named hardware presets."""
+import jax
 import pytest
 
-from repro.launch.roofline import HW, HW_PRESETS, analyze, collective_bytes, get_hw
+from repro.launch.roofline import (HW, HW_PRESETS, analyze, collective_bytes,
+                                   device_hw, get_hw)
 
 HLO_SAMPLE = """
 HloModule jit_step
@@ -49,7 +51,7 @@ def test_collective_bytes_ignores_plain_ops():
 
 
 def test_analyze_terms_and_dominance():
-    hw = HW(peak_flops=100.0, hbm_bw=10.0, ici_bw=1.0, chips=2)
+    hw = HW(peak_flops=100.0, hbm_bw=10.0, ici_bw=1.0, name="test", chips=2)
     rep = analyze(
         arch="x", shape="y", mesh_name="m",
         cost={"flops": 1000.0, "bytes accessed": 50.0},
@@ -66,25 +68,41 @@ def test_analyze_terms_and_dominance():
 def test_analyze_zero_flops_safe():
     rep = analyze(arch="x", shape="y", mesh_name="m",
                   cost={"flops": 0.0, "bytes accessed": 0.0}, hlo_text="",
-                  memory={}, model_flops_global=1.0)
+                  memory={}, model_flops_global=1.0,
+                  hw=get_hw("TPU v5 lite"))
     assert rep.useful_ratio == 0.0
 
 
-def test_get_hw_presets(monkeypatch):
-    monkeypatch.delenv("REPRO_HW", raising=False)
-    assert get_hw().name == "v5e"  # historical default
-    for name, hw in HW_PRESETS.items():
-        got = get_hw(name)
-        assert got.name == name and got.peak_flops == hw.peak_flops
+def test_get_hw_presets():
+    for kind, hw in HW_PRESETS.items():
+        got = get_hw(kind)
+        assert got.name == kind and got.peak_flops == hw.peak_flops
     # chips override rides along without mutating the preset.
-    assert get_hw("v4", chips=64).chips == 64
-    assert get_hw("v4").chips == HW_PRESETS["v4"].chips  # preset untouched
+    assert get_hw("TPU v4", chips=64).chips == 64
+    assert get_hw("TPU v4").chips == HW_PRESETS["TPU v4"].chips
 
 
 def test_get_hw_env_and_errors(monkeypatch):
-    monkeypatch.setenv("REPRO_HW", "v5p")
-    assert get_hw().name == "v5p"
-    # Explicit argument beats the env var.
-    assert get_hw("v6e").name == "v6e"
-    with pytest.raises(ValueError):
-        get_hw("tpu9000")
+    # The table is keyed by device_kind alone: no environment variable
+    # or short name selects a preset, and an unknown kind is an error.
+    monkeypatch.setenv("REPRO_HW", "TPU v4")
+    v5e = get_hw("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.hbm_bw) == (197e12, 819e9)
+    for bad in ("tpu9000", "v5e", "cpu"):
+        with pytest.raises(ValueError):
+            get_hw(bad)
+
+
+@pytest.mark.parametrize("kind,ok", [("TPU v5 lite", True), ("TPU v7x", False),
+                                     ("cpu", False)])
+def test_device_hw_resolves_device_kind(kind, ok):
+    class FakeDevice:
+        device_kind = kind
+
+    if ok:
+        hw = device_hw(FakeDevice())
+        assert hw.name == kind and hw.peak_flops == 197e12
+        assert hw.chips == len(jax.devices())
+    else:
+        with pytest.raises(ValueError, match="no peak table entry"):
+            device_hw(FakeDevice())
