@@ -40,6 +40,8 @@ from typing import Any, Iterator
 
 import numpy as np
 
+from repro.obs.spans import span
+
 __all__ = [
     "CheckpointCorruptError",
     "CheckpointManager",
@@ -369,16 +371,14 @@ def load_pytree(path: str, *, verify: bool = True) -> tuple[Any, dict[str, Any]]
 class CheckpointOp:
     """One timed save/restore operation (observability attribution).
 
-    ``start_s`` is the host monotonic-ish wall clock (``time.time``)
-    when the op began; ``wall_ms`` its duration.  The op log feeds the
-    Perfetto timeline's checkpoint track
-    (:func:`repro.obs.timeline.build_timeline`) and the MFU-gap
-    waterfall's ``checkpoint_stall`` component.
+    ``wall_ms`` is its duration.  The op log feeds the MFU-gap
+    waterfall's ``checkpoint_stall`` component; on the profiler's clock
+    each op is a ``ckpt.save`` / ``ckpt.restore`` span
+    (:mod:`repro.obs.spans`).
     """
 
     kind: str  # "save" | "restore"
     step: int  # checkpoint step (-1 when a restore found nothing)
-    start_s: float
     wall_ms: float
 
 
@@ -410,11 +410,9 @@ class CheckpointManager:
             )
         os.makedirs(self.root, exist_ok=True)
 
-    def _record_op(self, kind: str, step: int, start_s: float, t0: float) -> None:
+    def _record_op(self, kind: str, step: int, t0: float) -> None:
         wall_ms = (time.perf_counter() - t0) * 1e3
-        self.ops.append(
-            CheckpointOp(kind=kind, step=step, start_s=start_s, wall_ms=wall_ms)
-        )
+        self.ops.append(CheckpointOp(kind=kind, step=step, wall_ms=wall_ms))
         if self._h_op is not None:
             self._h_op.observe(wall_ms, op=kind)
 
@@ -450,17 +448,18 @@ class CheckpointManager:
         extras: dict[str, Any] | None = None,
         meta: dict[str, Any] | None = None,
     ) -> str:
-        start_s, t0 = time.time(), time.perf_counter()
-        self._collect_tmp_litter()
-        path = save_pytree(
-            self.step_path(step),
-            tree,
-            specs=specs,
-            extras=extras,
-            meta={"step": int(step), **(meta or {})},
-        )
-        self._prune()
-        self._record_op("save", int(step), start_s, t0)
+        t0 = time.perf_counter()
+        with span("ckpt.save", step=int(step)):
+            self._collect_tmp_litter()
+            path = save_pytree(
+                self.step_path(step),
+                tree,
+                specs=specs,
+                extras=extras,
+                meta={"step": int(step), **(meta or {})},
+            )
+            self._prune()
+        self._record_op("save", int(step), t0)
         return path
 
     def _collect_tmp_litter(self) -> None:
@@ -475,11 +474,12 @@ class CheckpointManager:
 
     # -- restore --------------------------------------------------------
     def restore(self, step: int, *, verify: bool = True):
-        start_s, t0 = time.time(), time.perf_counter()
+        t0 = time.perf_counter()
         try:
-            return load_pytree(self.step_path(step), verify=verify)
+            with span("ckpt.restore", step=int(step)):
+                return load_pytree(self.step_path(step), verify=verify)
         finally:
-            self._record_op("restore", int(step), start_s, t0)
+            self._record_op("restore", int(step), t0)
 
     def restore_latest(self, *, verify: bool = True, on_corrupt: str = "flag"):
         """Newest complete checkpoint -> (tree, manifest), or ``None``
@@ -493,13 +493,14 @@ class CheckpointManager:
             raise ValueError(
                 f"on_corrupt must be 'flag' or 'ignore', got {on_corrupt!r}"
             )
-        start_s, t0 = time.time(), time.perf_counter()
+        t0 = time.perf_counter()
         restored = -1
         try:
             for step in reversed(self.steps()):
                 path = self.step_path(step)
                 try:
-                    out = load_pytree(path, verify=verify)
+                    with span("ckpt.restore", step=step):
+                        out = load_pytree(path, verify=verify)
                     restored = step
                     return out
                 except CheckpointCorruptError:
@@ -507,7 +508,7 @@ class CheckpointManager:
                         self._flag_corrupt(path)
             return None
         finally:
-            self._record_op("restore", restored, start_s, t0)
+            self._record_op("restore", restored, t0)
 
     def _flag_corrupt(self, path: str) -> None:
         """Rename to a unique ``*.corrupt`` name; never let the rename

@@ -43,6 +43,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.rearrangement import Rearrangement
+from repro.data.packing import CapacityOverflow
 from repro.utils import round_up as _round_up
 
 __all__ = ["CommPlan", "build_comm_plan", "apply_comm_plan", "plan_to_device"]
@@ -109,6 +110,7 @@ def _layout(insts: np.ndarray, slots: np.ndarray, lengths: np.ndarray, d: int):
 def build_comm_plan(
     pi: Rearrangement, cap_in: int, cap_out: int, *, chunk_pad_to: int = 8,
     src_starts: np.ndarray | None = None, chunk_cap: int | None = None,
+    stream: str = "exchange",
 ) -> CommPlan:
     """Compile a Rearrangement into static-shape transport arrays.
 
@@ -116,7 +118,8 @@ def build_comm_plan(
     shard buffer (flat, aligned with pi's entries).  Defaults to packed
     contiguous layout in src_slot order; the orchestrator passes explicit
     starts when the source layout has alignment gaps (downsample) or
-    padded rows (audio).
+    padded rows (audio).  A plan that does not fit a capacity raises
+    :class:`CapacityOverflow` naming ``stream``.
     """
     d = pi.d
     n = pi.n
@@ -124,14 +127,17 @@ def build_comm_plan(
     if src_starts is None:
         src_starts, src_totals = _layout(pi.src_inst, pi.src_slot, lengths, d)
         if src_totals.max(initial=0) > cap_in:
-            raise ValueError(f"cap_in={cap_in} < max shard tokens {src_totals.max()}")
+            raise CapacityOverflow(
+                stream, f"cap_in={cap_in} < max shard tokens {src_totals.max()}")
     else:
         src_starts = np.asarray(src_starts, dtype=np.int64)
         if n and (src_starts + lengths).max() > cap_in:
-            raise ValueError(f"cap_in={cap_in} < max src end {(src_starts + lengths).max()}")
+            raise CapacityOverflow(
+                stream, f"cap_in={cap_in} < max src end {(src_starts + lengths).max()}")
     dst_starts, dst_totals = _layout(pi.dst_inst, pi.dst_slot, lengths, d)
     if dst_totals.max(initial=0) > cap_out:
-        raise ValueError(f"cap_out={cap_out} < max shard tokens {dst_totals.max()}")
+        raise CapacityOverflow(
+            stream, f"cap_out={cap_out} < max shard tokens {dst_totals.max()}")
 
     pre_gather = np.zeros((d, cap_in), dtype=np.int32)
     input_offsets = np.zeros((d, d), dtype=np.int32)
@@ -175,7 +181,8 @@ def build_comm_plan(
     if chunk_cap is None:
         chunk_cap = _round_up(max(max_send, 1), chunk_pad_to)
     elif max_send > chunk_cap:
-        raise ValueError(f"peer chunk {max_send} > static chunk_cap {chunk_cap}")
+        raise CapacityOverflow(
+            stream, f"peer chunk {max_send} > static chunk_cap {chunk_cap}")
     pre_gather_dense = np.zeros((d, d * chunk_cap), dtype=np.int32)
     for s in range(d):
         for t in range(d):

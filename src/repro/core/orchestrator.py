@@ -33,8 +33,9 @@ from repro.core.dispatcher import BatchPostBalancingDispatcher, DispatchPlan
 from repro.core.pipeline import PipelinePlan, plan_pipeline
 from repro.core.rearrangement import Rearrangement, compose
 from repro.sharding.specs import stage_partition
-from repro.data.packing import pack_padded_stream, pack_stream
+from repro.data.packing import CapacityOverflow, pack_padded_stream, pack_stream
 from repro.data.synthetic import Example
+from repro.obs.spans import span
 from repro.utils import round_up as _round_up
 
 
@@ -49,6 +50,7 @@ def _ex_rng(seed: int, sid: int, tag: str) -> np.random.Generator:
 
 __all__ = [
     "Capacities",
+    "CapacityOverflow",
     "PhasePlans",
     "PlanAheadHandle",
     "OrchestratorReport",
@@ -103,6 +105,13 @@ class OrchestratorReport:
     # Pipeline mode (pp > 1): the simulated 1F1B + bubble-fill schedule
     # for this iteration (None when DP-only).
     pipeline: PipelinePlan | None = None
+    # Packed slots per stream ("llm", "text", each encoder's input
+    # stream): (real, slots), counted from the arrays just packed.
+    stream_tokens: dict[str, tuple[int, int]] = dataclasses.field(
+        default_factory=dict)
+    # Draws of this batch resampled before it fit, by the stream whose
+    # capacity overflowed (filled by the data pipeline).
+    resamples: dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -208,8 +217,11 @@ class MLLMGlobalOrchestrator:
             self._c_plans = metrics.counter(
                 "orch_plans", "phase-plan solves by mode",
                 labels=("mode",))
+            self._c_slots = metrics.counter(
+                "orch_stream_slots", "packed slots per stream, real or pad",
+                labels=("stream", "kind"))
         else:
-            self._h_solve = self._c_plans = None
+            self._h_solve = self._c_plans = self._c_slots = None
         self.vocab = vocab or cfg.vocab_size
         self.data_seed = 0
         self.instances_per_node = instances_per_node
@@ -302,6 +314,8 @@ class MLLMGlobalOrchestrator:
         self,
         examples_per_instance: Sequence[Sequence[Example]],
         caps: Capacities | None = None,
+        *,
+        step: int | None = None,
     ) -> PhasePlans:
         """Steps 1-3: per-phase post-balancing plans + composition.
 
@@ -312,6 +326,9 @@ class MLLMGlobalOrchestrator:
         sort/scan kernels, and one dispatcher per modality is exactly the
         paper's Fig. 4 layout).  Without ``caps`` the communicator plans
         are skipped (plan-only accounting, e.g. the overhead benchmark).
+        ``step`` (the batch index) tags the ``dispatch.*`` spans; under
+        ``concurrent_dispatch`` the encoders' solves run on their
+        dispatchers' threads and have none.
         """
         cfg = self.cfg
         t0 = time.perf_counter()
@@ -350,13 +367,16 @@ class MLLMGlobalOrchestrator:
                 name: self.enc_dispatchers[name].submit(lens)
                 for name, lens in enc_lengths.items()
             }
-            llm_plan = self.llm_dispatcher.plan(llm_lengths)
+            with span("dispatch.solve.llm", step=step):
+                llm_plan = self.llm_dispatcher.plan(llm_lengths)
             for name, ticket in tickets.items():
                 enc_plans[name] = ticket.result()
         else:
-            llm_plan = self.llm_dispatcher.plan(llm_lengths)
+            with span("dispatch.solve.llm", step=step):
+                llm_plan = self.llm_dispatcher.plan(llm_lengths)
             for name, lens in enc_lengths.items():
-                enc_plans[name] = self.enc_dispatchers[name].plan(lens)
+                with span(f"dispatch.solve.{name}", step=step):
+                    enc_plans[name] = self.enc_dispatchers[name].plan(lens)
         phase_ms["llm"] = llm_plan.solve_ms
         for name, plan in enc_plans.items():
             phase_ms[name] = plan.solve_ms
@@ -367,28 +387,30 @@ class MLLMGlobalOrchestrator:
         pi_es: dict[str, Rearrangement] = {}
         composed: dict[str, Rearrangement] = {}
         comm_plans: dict[str, CommPlan] = {}
-        for e in cfg.encoders:
-            plan = enc_plans[e.name]
-            # pi_e's orig_slot indexes the SUBSET of modality-bearing
-            # examples; remap to full example slots so composition joins.
-            pi_e = _remap_subset_slots(plan.pi, examples_per_instance, e.name)
-            pi_es[e.name] = pi_e
-            comp = compose(pi_m, pi_e)
-            # Payload lengths after the connector downsample.
-            comp = dataclasses.replace(
-                comp, lengths=np.ceil(comp.lengths / e.downsample).astype(np.int64)
-            )
-            composed[e.name] = comp
-            if caps is not None:
-                src_starts = _encoder_out_starts(pi_e, caps.enc_row[e.name],
-                                                 e.downsample)
-                comm_plans[e.name] = build_comm_plan(
+        with span("dispatch.compose", step=step):
+            for e in cfg.encoders:
+                plan = enc_plans[e.name]
+                # pi_e's orig_slot indexes the SUBSET of modality-bearing
+                # examples; remap to full example slots so composition joins.
+                pi_e = _remap_subset_slots(plan.pi, examples_per_instance, e.name)
+                pi_es[e.name] = pi_e
+                comp = compose(pi_m, pi_e)
+                # Payload lengths after the connector downsample.
+                comp = dataclasses.replace(
                     comp,
-                    caps.enc_in[e.name] // e.downsample,
-                    caps.enc_out[e.name],
-                    src_starts=src_starts,
-                    chunk_cap=caps.chunk[e.name],
-                )
+                    lengths=np.ceil(comp.lengths / e.downsample).astype(np.int64))
+                composed[e.name] = comp
+                if caps is not None:
+                    src_starts = _encoder_out_starts(pi_e, caps.enc_row[e.name],
+                                                     e.downsample)
+                    comm_plans[e.name] = build_comm_plan(
+                        comp,
+                        caps.enc_in[e.name] // e.downsample,
+                        caps.enc_out[e.name],
+                        src_starts=src_starts,
+                        chunk_cap=caps.chunk[e.name],
+                        stream=f"{e.name}.exchange",
+                    )
         phase_ms["compose"] = (time.perf_counter() - tc) * 1e3
 
         # ---- Pipeline schedule (pp > 1): 1F1B microbatch split over
@@ -406,8 +428,6 @@ class MLLMGlobalOrchestrator:
             )
             phase_ms["pipeline"] = pipeline.solve_ms
 
-        if self.adaptive is not None:
-            self.adaptive.record_plan_spans(phase_ms)
         if self._h_solve is not None:
             for name, ms in phase_ms.items():
                 self._h_solve.observe(ms, phase=name)
@@ -427,6 +447,8 @@ class MLLMGlobalOrchestrator:
         self,
         examples_per_instance: Sequence[Sequence[Example]],
         caps: Capacities,
+        *,
+        step: int | None = None,
     ) -> PlanAheadHandle:
         """Run :meth:`plan_phases` on a background thread; the returned
         handle's ``result()`` reports the latency that was actually
@@ -435,7 +457,8 @@ class MLLMGlobalOrchestrator:
 
         def run() -> None:
             try:
-                box["plans"] = self.plan_phases(examples_per_instance, caps)
+                box["plans"] = self.plan_phases(examples_per_instance, caps,
+                                                step=step)
             except BaseException as e:
                 box["error"] = e
 
@@ -452,6 +475,7 @@ class MLLMGlobalOrchestrator:
         plans: PhasePlans | None = None,
         *,
         exposed_ms: float | None = None,
+        step: int | None = None,
     ) -> tuple[dict[str, np.ndarray], OrchestratorReport]:
         cfg = self.cfg
         overlapped = plans is not None
@@ -473,7 +497,7 @@ class MLLMGlobalOrchestrator:
                 self._c_plans.inc(mode="replanned")
         if plans is None:
             t_replan = time.perf_counter()
-            plans = self.plan_phases(examples_per_instance, caps)
+            plans = self.plan_phases(examples_per_instance, caps, step=step)
             if replanned:
                 exposed_ms = ((exposed_ms or 0.0)
                               + (time.perf_counter() - t_replan) * 1e3)
@@ -510,9 +534,26 @@ class MLLMGlobalOrchestrator:
         report.coeff_version = plans.coeff_version
         report.replanned = replanned
         report.pipeline = plans.pipeline
+        report.stream_tokens = self._stream_tokens(batch, caps)
         if self._c_plans is not None:
             self._c_plans.inc(mode="overlapped" if overlapped else "sync")
+            for name, (real, slots) in report.stream_tokens.items():
+                self._c_slots.inc(real, stream=name, kind="real")
+                self._c_slots.inc(slots - real, stream=name, kind="pad")
         return batch, report
+
+    def _stream_tokens(self, batch, caps) -> dict[str, tuple[int, int]]:
+        """(real, slots) of each packed stream: segment id 0 is padding,
+        and a text slot is real when it lands inside the LLM stream."""
+        def count(seg):
+            return int(np.count_nonzero(seg)), int(seg.size)
+
+        out = {"llm": count(batch["llm_seg"] if "llm_seg" in batch else batch["seg"])}
+        if "text_dst" in batch:
+            out["text"] = count(batch["text_dst"] < caps.llm)
+        for e in self.cfg.encoders:
+            out[e.name] = count(batch[f"enc_{e.name}_seg"])
+        return out
 
     # ------------------------------------------------------------------
     def observe_phase_times(
@@ -545,7 +586,8 @@ class MLLMGlobalOrchestrator:
     def _pack_text(self, examples, ex_id, pi_m, caps, rng):
         dest_lengths = pi_m.dest_lengths()
         seg_ids = _dest_seg_ids(pi_m, ex_id)
-        seg, pos, starts = pack_stream(dest_lengths, caps.llm, seg_ids=seg_ids)
+        seg, pos, starts = pack_stream(dest_lengths, caps.llm, seg_ids=seg_ids,
+                                       stream="llm")
         tokens = np.zeros(seg.shape, np.int32)
         for i in range(self.d):
             for j, l in enumerate(np.asarray(dest_lengths[i], np.int64)):
@@ -587,7 +629,8 @@ class MLLMGlobalOrchestrator:
                 sid = ex_id[(int(pi_m.orig_inst[k]), int(pi_m.orig_slot[k]))]
                 L = ex.total_len(self.downsample)
                 if off + L > caps.llm:
-                    raise ValueError(f"llm cap {caps.llm} overflow on shard {t}")
+                    raise CapacityOverflow(
+                        "llm", f"cap {caps.llm} overflow on shard {t}")
                 llm_seg[t, off : off + L] = sid
                 llm_pos[t, off : off + L] = np.arange(L)
 
@@ -606,7 +649,8 @@ class MLLMGlobalOrchestrator:
                         n_t = (ex.text_len - tpart * (text_parts - 1)
                                if seen_text == text_parts - 1 else tpart)
                         if toff + n_t > caps.text:
-                            raise ValueError(f"text cap {caps.text} overflow")
+                            raise CapacityOverflow(
+                                "text", f"cap {caps.text} overflow")
                         tokens[t, toff : toff + n_t] = ex_tokens[ti : ti + n_t]
                         text_dst[t, toff : toff + n_t] = np.arange(cur, cur + n_t)
                         is_text[cur - off : cur - off + n_t] = True
@@ -653,10 +697,10 @@ class MLLMGlobalOrchestrator:
         seg_ids = _dest_seg_ids(pi_e, ex_id)
         if e.padded:
             seg, pos, starts = pack_padded_stream(dest_lengths, cap_in, row,
-                                                  seg_ids=seg_ids)
+                                                  seg_ids=seg_ids, stream=e.name)
         else:
             seg, pos, starts = pack_stream(dest_lengths, cap_in, seg_ids=seg_ids,
-                                           align=e.downsample)
+                                           align=e.downsample, stream=e.name)
         embeds = _fill_embeds(dest_lengths, starts, seg_ids, cap_in,
                               e.embed_dim, self.data_seed, e.name)
 
@@ -692,7 +736,8 @@ class MLLMGlobalOrchestrator:
         row = caps.enc_row[e.name]
         seg_ids = _dest_seg_ids(pi_e, ex_id)
         dest_lengths = pi_e.dest_lengths()
-        seg, pos, starts = pack_padded_stream(dest_lengths, cap_in, row, seg_ids=seg_ids)
+        seg, pos, starts = pack_padded_stream(dest_lengths, cap_in, row,
+                                              seg_ids=seg_ids, stream=e.name)
         embeds = _fill_embeds(dest_lengths, starts, seg_ids, cap_in,
                               e.embed_dim, self.data_seed, e.name)
         # Post-exchange layout at the decoder shard: packed by dst_slot.

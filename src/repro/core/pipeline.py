@@ -39,7 +39,7 @@ mean the same FLOPs.  Backward compute is ``bwd_ratio`` (default 2.0)
 times forward.  Everything here is host-side planning over lengths --
 the same dry-run contract as the dispatcher -- consumed by the
 orchestrator, the gap waterfall (``pipeline_bubble_s{k}`` components),
-the ledger, the Perfetto timeline, and ``benchmarks/pipeline_bubbles``.
+the ledger and ``benchmarks/pipeline_bubbles``.
 
 See docs/pipeline.md for a worked schedule diagram.
 """

@@ -11,7 +11,18 @@ from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["pack_stream", "pack_padded_stream", "random_tokens"]
+__all__ = ["CapacityOverflow", "pack_stream", "pack_padded_stream", "random_tokens"]
+
+
+class CapacityOverflow(ValueError):
+    """A batch does not fit one of its static capacities.  ``stream``
+    names the capacity: ``llm``, ``text``, an encoder's input stream by
+    the encoder's name, or ``<encoder>.exchange`` for the static shapes
+    of its exchange plan.  The data pipeline resamples on it."""
+
+    def __init__(self, stream: str, message: str) -> None:
+        super().__init__(f"{stream}: {message}")
+        self.stream = stream
 
 
 def pack_stream(
@@ -20,12 +31,14 @@ def pack_stream(
     *,
     seg_ids: list[np.ndarray] | None = None,
     align: int = 1,
+    stream: str = "unnamed",
 ) -> tuple[np.ndarray, np.ndarray, list[np.ndarray]]:
     """Build (seg [S,cap], pos [S,cap], starts per shard) for packed layout.
 
     ``seg_ids[i][j]``: id (>0) of example j on shard i; defaults to a
     running counter unique per shard.  ``align``: round each example's
     start offset up to this multiple (connector downsample alignment).
+    ``stream`` names the stream in a :class:`CapacityOverflow`.
     """
     S = len(dest_lengths)
     seg = np.zeros((S, cap), np.int32)
@@ -37,7 +50,8 @@ def pack_stream(
         for j, l in enumerate(np.asarray(lens, np.int64)):
             sid = int(seg_ids[i][j]) if seg_ids is not None else j + 1
             if off + l > cap:
-                raise ValueError(f"shard {i}: {off + l} tokens > cap {cap}")
+                raise CapacityOverflow(stream,
+                                       f"shard {i}: {off + l} tokens > cap {cap}")
             seg[i, off : off + l] = sid
             pos[i, off : off + l] = np.arange(l)
             st[j] = off
@@ -53,9 +67,11 @@ def pack_padded_stream(
     row_len: int,
     *,
     seg_ids: list[np.ndarray] | None = None,
+    stream: str = "unnamed",
 ) -> tuple[np.ndarray, np.ndarray, list[np.ndarray]]:
     """Padded layout: example j of a shard occupies row j*row_len; tokens
-    beyond its length stay seg=0 (padding).  cap must be >= rows*row_len."""
+    beyond its length stay seg=0 (padding).  cap must be >= rows*row_len.
+    ``stream`` names the stream in a :class:`CapacityOverflow`."""
     S = len(dest_lengths)
     seg = np.zeros((S, cap), np.int32)
     pos = np.zeros((S, cap), np.int32)
@@ -65,9 +81,10 @@ def pack_padded_stream(
         for j, l in enumerate(np.asarray(lens, np.int64)):
             off = j * row_len
             if off + row_len > cap:
-                raise ValueError(f"shard {i}: padded rows exceed cap {cap}")
+                raise CapacityOverflow(stream,
+                                       f"shard {i}: padded rows exceed cap {cap}")
             if l > row_len:
-                raise ValueError(f"example len {l} > row_len {row_len}")
+                raise CapacityOverflow(stream, f"example len {l} > row_len {row_len}")
             sid = int(seg_ids[i][j]) if seg_ids is not None else j + 1
             seg[i, off : off + l] = sid
             pos[i, off : off + l] = np.arange(l)

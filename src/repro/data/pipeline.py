@@ -26,6 +26,13 @@ reproducible.  The retry path (capacity overflow on a pathological
 draw) bumps ``attempt`` deterministically instead of consuming from a
 shared stream.  ``cursor`` is the index of the next batch the consumer
 will receive -- the value a checkpoint's ``DataCursor`` records.
+
+Each resample is counted by the stream whose capacity overflowed
+(``CapacityOverflow.stream``): per batch on its ``report.resamples``,
+and on the orchestrator's metrics registry, where it has one, as
+``loader_resamples{stream}``.  The worker's and the consumer's work are
+``loader.*`` spans (:mod:`repro.obs.spans`), each tagged with the batch
+index as ``step``.
 """
 from __future__ import annotations
 
@@ -38,6 +45,7 @@ import numpy as np
 
 from repro.core.orchestrator import Capacities, MLLMGlobalOrchestrator
 from repro.data.synthetic import Example, TaskMix, sample_examples
+from repro.obs.spans import span
 
 __all__ = ["PrefetchingLoader"]
 
@@ -71,6 +79,10 @@ class PrefetchingLoader:
         self.exposed_ms_total = 0.0
         self.batches_produced = 0
         self.batches_consumed = 0
+        reg = orchestrator.metrics
+        self._c_resamples = None if reg is None else reg.counter(
+            "loader_resamples", "draws resampled on a capacity overflow, by stream",
+            labels=("stream",))
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._worker, daemon=True)
         self._thread.start()
@@ -106,6 +118,7 @@ class PrefetchingLoader:
     def _worker(self) -> None:
         index = self.start_index
         attempts = 0
+        overflows: dict[str, int] = {}  # this batch's resamples by stream
         pending = None  # (index, examples, PlanAheadHandle) for index+1
         while not self._stop.is_set():
             t0 = time.perf_counter()
@@ -113,8 +126,9 @@ class PrefetchingLoader:
                 _, examples, handle = pending
                 pending = None
             else:
-                examples = self._sample(index, attempts)
-                handle = (self.orch.plan_ahead(examples, self.caps)
+                with span("loader.sample", step=index):
+                    examples = self._sample(index, attempts)
+                handle = (self.orch.plan_ahead(examples, self.caps, step=index)
                           if self.plan_ahead else None)
             if self.plan_ahead and (pending is None or pending[0] != index + 1):
                 # Launch step k+1's plans before packing step k: the
@@ -122,43 +136,54 @@ class PrefetchingLoader:
                 # forward pass, so by the time the worker loops around
                 # the plans are ready (exposed ~ 0).  On a retry of step
                 # k the still-valid pending plan for k+1 is kept as is.
-                nxt = self._sample(index + 1)
-                pending = (index + 1, nxt, self.orch.plan_ahead(nxt, self.caps))
+                with span("loader.sample", step=index + 1):
+                    nxt = self._sample(index + 1)
+                pending = (index + 1, nxt,
+                           self.orch.plan_ahead(nxt, self.caps, step=index + 1))
             try:
                 rng = self._batch_rng(index, attempts)
+                plans = exposed_ms = None
                 if handle is not None:
-                    plans, exposed_ms = handle.result()
+                    with span("loader.plan_wait", step=index):
+                        plans, exposed_ms = handle.result()
+                with span("loader.pack", step=index):
                     batch, report = self.orch.plan_and_pack(
                         examples, self.caps, rng, plans,
-                        exposed_ms=exposed_ms,
-                    )
-                else:
-                    batch, report = self.orch.plan_and_pack(
-                        examples, self.caps, rng)
-            except ValueError:
+                        exposed_ms=exposed_ms, step=index)
+            except ValueError as err:
                 # Capacity overflow on a pathological draw: retry the
                 # SAME index with a bumped attempt counter (replayable).
+                stream = getattr(err, "stream", "other")
+                overflows[stream] = overflows.get(stream, 0) + 1
+                if self._c_resamples is not None:
+                    self._c_resamples.inc(stream=stream)
                 attempts += 1
                 continue
+            report.resamples, overflows = overflows, {}
             dt = (time.perf_counter() - t0) * 1e3
             self.solve_ms_total += report.solve_ms
             self.exposed_ms_total += report.exposed_ms
             self.batches_produced += 1
             item = (batch, report, dt)
+            try:
+                self.q.put_nowait(item)
+            except queue.Full:
+                with span("loader.queue_full", step=index):
+                    while not self._stop.is_set():
+                        try:
+                            self.q.put(item, timeout=0.1)
+                            break
+                        except queue.Full:
+                            continue
             index += 1
             attempts = 0
-            while not self._stop.is_set():
-                try:
-                    self.q.put(item, timeout=0.1)
-                    break
-                except queue.Full:
-                    continue
 
     def __iter__(self) -> Iterator:
         return self
 
     def __next__(self):
-        item = self.q.get()
+        with span("loader.next", step=self.cursor):
+            item = self.q.get()
         self.batches_consumed += 1
         return item
 

@@ -10,19 +10,24 @@ the same entrypoint runs the full configs under the production mesh.
 
 Pipeline mode: ``--pp N`` (N > 1) partitions the LLM backbone into N
 stages and plans a 1F1B microbatch schedule with encoder bubble-fill
-per step (docs/pipeline.md); the ledger gains per-stage bubble series,
-the waterfall switches to its ``pipeline_bubble_s{k}`` components, and
-the Perfetto timeline gets one lane per stage.
+per step (docs/pipeline.md); the ledger gains per-stage bubble series
+and the waterfall switches to its ``pipeline_bubble_s{k}`` components.
+
+Profiling: ``--trace-out DIR`` runs the training loop under
+``jax.profiler.trace(DIR)``.  The capture holds the device operations
+and the program's host spans (``loader.*``, ``dispatch.*``, ``ckpt.*``;
+:mod:`repro.obs.spans`) on one clock, and every device operation of the
+step keeps its phase (``encoder.<name>``, ``exchange.<name>``, ``llm``,
+``lm_head``, ``optimizer``) in its ``op_name``.  Open it in
+TensorBoard's profile plugin or in Perfetto.
 
 Observability: ``--metrics-dir DIR`` turns on the unified metrics plane
 (:mod:`repro.obs`): an OpenMetrics textfile (``metrics.prom``,
 atomically rewritten every ``--metrics-every`` steps), a crash-safe
 JSONL flight recorder (``flight.jsonl``) carrying run metadata and
 structured alert events (cost-model drift, checkpoint corruption
-fallbacks, MoE drop spikes, stale-plan re-plans), and one merged
-Perfetto timeline (``timeline.json``) with orchestrator spans,
-checkpoint save/restore spans and MFU/goodput/imbalance counter
-tracks.  On top of the recording plane sits the attribution plane: a
+fallbacks, MoE drop spikes, stale-plan re-plans).  On top of the
+recording plane sits the attribution plane: a
 per-step MFU-gap waterfall (:class:`repro.obs.GapWaterfall`, recorded
 as ``waterfall`` flight events), online anomaly detection over every
 ledger/waterfall series (:class:`repro.obs.AnomalyMonitor`), and an
@@ -89,7 +94,7 @@ from repro.data.synthetic import Example
 from repro.launch.mesh import make_mesh
 from repro.obs import (AlertBridge, AnomalyMonitor, FlightRecorder,
                        GapWaterfall, MetricsRegistry, MetricsServer,
-                       StepLedger, build_timeline, render_text,
+                       StepLedger, render_text,
                        set_registry, triage, write_openmetrics)
 from repro.sharding.specs import opt_state_specs, param_specs, to_shardings
 from repro.telemetry import AdaptiveOrchestration
@@ -153,12 +158,14 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
                     help="online cost-model calibration: measured step "
                          "times refit the balancing coefficients "
                          "(repro.telemetry)")
-    ap.add_argument("--trace-out", default=None,
-                    help="write the telemetry Chrome-trace/Perfetto JSON "
-                         "here on exit (requires --adaptive)")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="profile the training loop into DIR "
+                         "(jax.profiler.trace): device operations with "
+                         "their step phases and the host spans on one "
+                         "clock; open in TensorBoard or Perfetto")
     ap.add_argument("--metrics-dir", default=None,
                     help="enable the obs plane: write metrics.prom, "
-                         "flight.jsonl and timeline.json here")
+                         "flight.jsonl and triage.json here")
     ap.add_argument("--metrics-every", type=int, default=10,
                     help="flush the exporters every N steps")
     ap.add_argument("--inject-drift", type=int, default=None, metavar="STEP",
@@ -404,7 +411,6 @@ def train(cfg, args: argparse.Namespace) -> list[dict]:
 
     t0 = time.time()
     pending_ckpt_ms = 0.0  # save wall charged to the NEXT step's waterfall
-    last_pipeline = None  # newest PipelinePlan (pp > 1): timeline lanes
     last_done = None  # host clock when the previous step was seen complete
     records: list[dict] = []
 
@@ -415,7 +421,7 @@ def train(cfg, args: argparse.Namespace) -> list[dict]:
         later of the step's dispatch and the previous step's completion
         to its own completion: device-complete, not host-dispatch,
         time."""
-        nonlocal last_done, pending_ckpt_ms, last_pipeline
+        nonlocal last_done, pending_ckpt_ms
         jax.block_until_ready(m)
         now = time.perf_counter()
         step_ms = (now - max(t_dispatch, last_done or t_dispatch)) * 1e3
@@ -454,7 +460,6 @@ def train(cfg, args: argparse.Namespace) -> list[dict]:
                 # waterfall below picks the plan off the report and
                 # switches to its pipeline_bubble_s{k} algebra.
                 ledger.record_pipeline(it, report.pipeline)
-                last_pipeline = report.pipeline
             # The smoke path runs dense reference attention, so the
             # tile fraction the Pallas kernels would have skipped IS
             # dead compute actually paid this step -- but only for
@@ -496,8 +501,10 @@ def train(cfg, args: argparse.Namespace) -> list[dict]:
     # The step traces under the mesh, so Pallas kernels run per DP shard
     # (kernels.ops.per_dp_shard).  jax.set_mesh applies the mesh when it
     # is built and restores the previous one on exit: one for the loop.
+    profile = (jax.profiler.trace(args.trace_out) if args.trace_out
+               else contextlib.nullcontext())
     with (jax.set_mesh(mesh) if mesh is not None
-          else contextlib.nullcontext()):
+          else contextlib.nullcontext()), profile:
         try:
             for it in range(start_step, args.steps):
                 batch_np, report, _ = next(loader)
@@ -536,21 +543,12 @@ def train(cfg, args: argparse.Namespace) -> list[dict]:
         print("telemetry calibration summary:")
         print(json.dumps(adaptive.summary(), indent=1, default=str))
         print(f"stale plan-ahead re-plans: {orch.replans}")
-        if args.trace_out:
-            adaptive.export_chrome_trace(args.trace_out)
-            print(f"wrote phase trace to {args.trace_out} "
-                  f"(open in ui.perfetto.dev)")
+    if args.trace_out:
+        print(f"wrote a profile of the loop to {args.trace_out} "
+              f"(open in TensorBoard or ui.perfetto.dev)")
     if ledger is not None:
         write_openmetrics(os.path.join(args.metrics_dir, "metrics.prom"),
                           registry)
-        tl_path = os.path.join(args.metrics_dir, "timeline.json")
-        tl = build_timeline(
-            trace_buffer=adaptive.trace if adaptive is not None else None,
-            ledger=ledger, waterfall=waterfall,
-            checkpoint_ops=manager.ops if manager is not None else None,
-            pipeline=last_pipeline)
-        with open(tl_path, "w") as f:
-            json.dump(tl, f)
         triage_report = triage_now()
         with open(os.path.join(args.metrics_dir, "triage.json"), "w") as f:
             json.dump(triage_report, f, indent=1, default=str)
@@ -565,8 +563,7 @@ def train(cfg, args: argparse.Namespace) -> list[dict]:
         print(json.dumps(summary, indent=1, default=str))
         print(f"wrote {args.metrics_dir}/metrics.prom, flight.jsonl "
               f"({recorder.events_written} events, "
-              f"{len(alerts.alerts)} alerts), timeline.json "
-              f"(open in ui.perfetto.dev), triage.json")
+              f"{len(alerts.alerts)} alerts), triage.json")
     if server is not None:
         if args.serve_metrics_linger > 0:
             print(f"metrics server lingering {args.serve_metrics_linger:g}s "

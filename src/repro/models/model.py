@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import EncoderConfig, ModelConfig
 from repro.models.layers import init_dense, layer_norm, rms_norm
+from repro.obs.spans import phase
 from repro.models.transformer import (
     cross_decoder_stack,
     decoder_stack,
@@ -308,13 +309,15 @@ def forward(cfg: ModelConfig, params: Params, batch: dict[str, jnp.ndarray],
         x = _scatter_tokens(x, batch["text_dst"], text_emb)
         for e in cfg.encoders:
             p_e = params[f"encoder_{e.name}"]
-            enc_tok = run_encoder(
-                e, p_e, batch[f"enc_{e.name}_embeds"],
-                batch[f"enc_{e.name}_seg"], batch[f"enc_{e.name}_pos"],
-                base_cfg=cfg,
-            )
+            with phase(f"encoder.{e.name}"):
+                enc_tok = run_encoder(
+                    e, p_e, batch[f"enc_{e.name}_embeds"],
+                    batch[f"enc_{e.name}_seg"], batch[f"enc_{e.name}_pos"],
+                    base_cfg=cfg,
+                )
             if exchange is not None:
-                enc_tok = exchange(e.name, enc_tok)
+                with phase(f"exchange.{e.name}"):
+                    enc_tok = exchange(e.name, enc_tok)
             x = _scatter_tokens(x, batch[f"enc_{e.name}_dst"], enc_tok)
         seg, pos = batch["llm_seg"], batch["llm_pos"]
         labels = batch["llm_labels"]
@@ -323,11 +326,9 @@ def forward(cfg: ModelConfig, params: Params, batch: dict[str, jnp.ndarray],
         seg, pos = batch["seg"], batch["pos"]
         labels = batch["labels"]
 
-    x, aux = decoder_stack(cfg, params, x, seg, pos)
-    x = _final_norm(cfg, params, x)
-    lm_head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    loss_sum, n = chunked_xent(x, lm_head, labels, unroll=_xent_unroll(cfg))
-    return loss_sum, n, aux
+    with phase("llm"):
+        x, aux = decoder_stack(cfg, params, x, seg, pos)
+    return (*_lm_head_loss(cfg, params, x, labels), aux)
 
 
 def _forward_encdec(cfg, params, batch, exchange):
@@ -338,19 +339,27 @@ def _forward_encdec(cfg, params, batch, exchange):
     enc_in = jnp.einsum("ste,ed->std", batch[f"enc_{e.name}_embeds"].astype(dt),
                         p_e["input_proj"])
     enc_seg, enc_pos = batch[f"enc_{e.name}_seg"], batch[f"enc_{e.name}_pos"]
-    enc_out = encoder_stack(cfg, {"enc_layers": params["enc_layers"]},
-                            enc_in, enc_seg, enc_pos)
+    with phase(f"encoder.{e.name}"):
+        enc_out = encoder_stack(cfg, {"enc_layers": params["enc_layers"]},
+                                enc_in, enc_seg, enc_pos)
     if exchange is not None:
-        enc_out = exchange(e.name, enc_out)
+        with phase(f"exchange.{e.name}"):
+            enc_out = exchange(e.name, enc_out)
         enc_seg = batch[f"enc_{e.name}_seg_out"]
         enc_pos = batch[f"enc_{e.name}_pos_out"]
     x = jnp.take(params["embed"], batch["tokens"], axis=0)
-    x = cross_decoder_stack(cfg, params, x, batch["seg"], batch["pos"],
-                            enc_out, enc_seg, enc_pos)
-    x = _final_norm(cfg, params, x)
-    lm_head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    loss_sum, n = chunked_xent(x, lm_head, batch["labels"], unroll=_xent_unroll(cfg))
-    return loss_sum, n, jnp.float32(0.0)
+    with phase("llm"):
+        x = cross_decoder_stack(cfg, params, x, batch["seg"], batch["pos"],
+                                enc_out, enc_seg, enc_pos)
+    return (*_lm_head_loss(cfg, params, x, batch["labels"]), jnp.float32(0.0))
+
+
+def _lm_head_loss(cfg, params, x, labels):
+    """Final norm, LM head and the chunked loss: (sum_loss, n_tokens)."""
+    with phase("lm_head"):
+        x = _final_norm(cfg, params, x)
+        lm_head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        return chunked_xent(x, lm_head, labels, unroll=_xent_unroll(cfg))
 
 
 def _xent_unroll(cfg):

@@ -23,9 +23,10 @@ Dependency-free (numpy + stdlib) metrics subsystem:
     ``/metrics`` + ``/triage`` HTTP exporter.
   * :mod:`repro.obs.export` -- atomic OpenMetrics textfile, crash-safe
     JSONL flight recorder, and the alert bridge.
-  * :mod:`repro.obs.timeline` -- one merged Perfetto timeline across
-    orchestrator spans, engine step rows, checkpoint save/restore
-    spans, and counter tracks.
+  * :mod:`repro.obs.spans` -- host spans and device step phases on the
+    profiler's clock (``jax.profiler.TraceAnnotation`` and
+    ``jax.named_scope``), and the reader that attributes compiled
+    operations to phases.
 """
 from repro.obs.aggregate import (MetricsServer, aggregate_registries,
                                  merge_sketches, parse_openmetrics,
@@ -40,7 +41,6 @@ from repro.obs.ledger import (StepLedger, goodput_fraction, hw_mfu,
                               straggler_overhead, useful_flops_ratio)
 from repro.obs.registry import (Counter, Gauge, Histogram, MetricsRegistry,
                                 QuantileSketch, get_registry, set_registry)
-from repro.obs.timeline import build_timeline, export_timeline
 from repro.obs.triage import render_text, triage, triage_flight
 
 __all__ = [
@@ -59,8 +59,6 @@ __all__ = [
     "StepLedger",
     "WaterfallStep",
     "aggregate_registries",
-    "build_timeline",
-    "export_timeline",
     "get_registry",
     "goodput_fraction",
     "hw_mfu",

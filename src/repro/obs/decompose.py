@@ -102,8 +102,8 @@ class GapWaterfall:
 
     ``observe`` is the only hot-path call; it publishes each component
     as a labeled gauge (``mfu_gap_component{component=...}``) through
-    the registry, keeps ``(step, value)`` series for the timeline /
-    anomaly monitor, and returns the :class:`WaterfallStep` for the
+    the registry, keeps ``(step, value)`` series for the anomaly
+    monitor, and returns the :class:`WaterfallStep` for the
     flight recorder.
     """
 

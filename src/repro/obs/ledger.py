@@ -22,8 +22,7 @@ module is the ONE home of every utilization formula in the repo:
 (phase cost vectors, solve/exposed times) plus the train step's metrics
 dict into labeled registry series -- gauges for the canonical ratios,
 histograms for step/phase walls -- and keeps an in-memory
-``(step, value)`` series per metric for the Perfetto counter tracks in
-:mod:`repro.obs.timeline`.
+``(step, value)`` series per metric for the anomaly monitor.
 """
 from __future__ import annotations
 
@@ -181,11 +180,9 @@ class StepLedger:
         self._g_pipe_uplift = r.gauge(
             "pipeline_mfu_uplift",
             "projected MFU delta of bubble fill vs no-fill 1F1B")
-        # (step, value) series for the timeline's counter tracks.
+        # (step, value) series for the anomaly monitor.
         self.series: dict[str, list[tuple[int, float]]] = {}
         self.steps_recorded = 0
-        self._wall_ms_cum = 0.0
-        self.step_ts_ms: dict[int, float] = {}
 
     # ------------------------------------------------------------------
     def _track(self, name: str, step: int, value: float) -> None:
@@ -206,8 +203,6 @@ class StepLedger:
         self.steps_recorded += 1
         if step_ms is not None:
             self._h_step.observe(step_ms)
-            self._wall_ms_cum += step_ms
-        self.step_ts_ms[step] = self._wall_ms_cum
 
         mfu = None
         if report is not None:
@@ -277,7 +272,7 @@ class StepLedger:
         Publishes per-stage unfilled-bubble fractions (device-time
         share of each stage lane), the run's bubble-fill fraction and
         the projected MFU uplift, and keeps the per-stage series for
-        the timeline / anomaly monitor."""
+        the anomaly monitor."""
         if plan is None:
             return
         denom = float(plan.rank_total.max()) * plan.d

@@ -4,8 +4,8 @@ Dependency-free (pure stdlib) instrumentation substrate for the whole
 repo: the orchestrator, the training loop, the serving engine, and the
 kernel call sites all record into ONE :class:`MetricsRegistry` (the
 process default from :func:`get_registry`, or an explicit instance for
-tests), and the exporters in :mod:`repro.obs.export` /
-:mod:`repro.obs.timeline` read it back out.
+tests), and the exporters in :mod:`repro.obs.export` read it back
+out.
 
 Design points:
 

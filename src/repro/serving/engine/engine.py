@@ -39,6 +39,7 @@ import numpy as np
 from repro.configs.base import EngineConfig, ModelConfig
 from repro.core.cost_model import ServingCostModel
 from repro.obs.registry import QuantileSketch
+from repro.obs.spans import span
 from repro.serving.engine.kv_pool import PagedKVPool
 from repro.serving.engine.request import Request, RequestState, SequenceState
 from repro.serving.engine.scheduler import (
@@ -348,10 +349,12 @@ class Engine:
         t1 = time.perf_counter()
         prefill_tokens = 0
         if plan.prefill:
-            prefill_tokens = self._run_prefill(plan.prefill, step)
+            with span("engine.prefill", step=step):
+                prefill_tokens = self._run_prefill(plan.prefill, step)
         t2 = time.perf_counter()
         if plan.decode:
-            self._run_decode(plan.decode, step)
+            with span("engine.decode", step=step):
+                self._run_decode(plan.decode, step)
         t3 = time.perf_counter()
         n_preempted = sum(r.n_preemptions for r in self.requests) - pre_preempt
         self.step_timings.append(StepTiming(

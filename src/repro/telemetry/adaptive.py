@@ -121,7 +121,7 @@ class AdaptiveCostModel:
                 shard = shards[i] if shards is not None else i
                 self.trace.add(PhaseSample(
                     phase=self.phase, shard=int(shard), step=step,
-                    features=row, wall_ms=float(t), kind="exec"))
+                    features=row, wall_ms=float(t)))
         drifted = self.calibrator.observe(F, w)
         cand = self.calibrator.cost_model()
         if drifted or _lam_differs(self._current, cand, self.replan_tol):
@@ -241,19 +241,6 @@ class AdaptiveOrchestration:
                 out[phase] = m.observe(F, t_arr, step=step)
         return out
 
-    def record_plan_spans(self, phase_solve_ms: Mapping[str, float], *,
-                          step: int | None = None) -> None:
-        """Host dispatcher spans -> the trace (never used for fitting).
-
-        Defaults to the shared observation step counter (without
-        advancing it), so plan spans and exec samples line up."""
-        if step is None:
-            step = self._step
-        for phase, ms in phase_solve_ms.items():
-            self.trace.add(PhaseSample(
-                phase=phase, shard=0, step=step,
-                features=np.zeros(4), wall_ms=float(ms), kind="plan"))
-
     # -- checkpointing --------------------------------------------------
     def state_dict(self) -> dict:
         """JSON-able calibration state for all phases (the trace ring is
@@ -271,9 +258,6 @@ class AdaptiveOrchestration:
 
     def summary(self) -> dict[str, dict]:
         return {name: m.summary() for name, m in self.models.items()}
-
-    def export_chrome_trace(self, path) -> None:
-        self.trace.export_chrome_trace(path)
 
 
 class AdaptiveServingCostModel:
@@ -347,7 +331,7 @@ class AdaptiveServingCostModel:
             self.trace.add(PhaseSample(
                 phase="serve_prefill", shard=0, step=step,
                 features=np.array([n, 0.0, 0.0, 0.0]),
-                wall_ms=float(wall_ms), kind="exec"))
+                wall_ms=float(wall_ms)))
         drifted = self.calibrator.observe_prefill(token_counts, wall_ms)
         self._refresh()
         return drifted
@@ -358,7 +342,7 @@ class AdaptiveServingCostModel:
             self.trace.add(PhaseSample(
                 phase="serve_decode", shard=0, step=step,
                 features=np.array([float(batch), 0.0, 0.0, 0.0]),
-                wall_ms=float(wall_ms), kind="exec"))
+                wall_ms=float(wall_ms)))
         self.calibrator.observe_decode(batch, wall_ms)
         self._refresh()
 

@@ -12,25 +12,14 @@ A :class:`PhaseSample` pairs one mini-batch's feature vector
 ``cost_model.FEATURE_NAMES``) with the measured wall time of executing
 that batch on its shard.  Samples land in a fixed-capacity
 :class:`TraceBuffer` ring (O(1) append, no allocation churn on the hot
-path, oldest samples evicted), which can
-
-  * hand the calibrator its (X, y) regression window
-    (:meth:`TraceBuffer.design_matrix`), and
-  * export a Chrome-trace / Perfetto JSON timeline
-    (:meth:`TraceBuffer.export_chrome_trace`; open in ``ui.perfetto.dev``
-    or ``chrome://tracing``) with one track per (phase, shard) and the
-    host-side dispatcher spans alongside the device phase spans.
-
-Sample *kinds* separate the two time domains:
-
-  ``exec``  device execution of one phase's mini-batch (feeds calibration)
-  ``plan``  host dispatcher/composition time (``PhasePlans`` accounting;
-            never used for coefficient fitting, but visible in the trace)
+path, oldest samples evicted), which hands the calibrator its (X, y)
+regression window (:meth:`TraceBuffer.design_matrix`).  It is a sample
+store, not a timeline: host spans and device phases are read from the
+profiler's trace (:mod:`repro.obs.spans`).
 """
 from __future__ import annotations
 
 import dataclasses
-import json
 import threading
 from typing import Iterable
 
@@ -50,8 +39,6 @@ class PhaseSample:
     step: int
     features: np.ndarray  # (N_FEATURES,) float64
     wall_ms: float
-    kind: str = "exec"  # "exec" (device phase) | "plan" (host dispatcher)
-    ts_ms: float | None = None  # optional start timestamp (trace export)
 
     def __post_init__(self) -> None:
         f = np.asarray(self.features, dtype=np.float64).reshape(-1)
@@ -64,19 +51,18 @@ class PhaseSample:
     @classmethod
     def from_lengths(cls, phase: str, lengths, wall_ms: float, *,
                      shard: int = 0, step: int = 0, padding: bool = False,
-                     kind: str = "exec", ts_ms: float | None = None,
                      ) -> "PhaseSample":
         return cls(phase=phase, shard=shard, step=step,
                    features=length_features(lengths, padding),
-                   wall_ms=float(wall_ms), kind=kind, ts_ms=ts_ms)
+                   wall_ms=float(wall_ms))
 
 
 class TraceBuffer:
     """Fixed-capacity ring buffer of :class:`PhaseSample`.
 
-    Thread-safe: the plan-ahead worker records host dispatcher spans
-    while the consumer thread records measured phase times, so the ring
-    pointer update and snapshot reads are taken under a lock."""
+    Thread-safe: samples may be added from any thread while another
+    reads a snapshot, so the ring pointer update and snapshot reads are
+    taken under a lock."""
 
     def __init__(self, capacity: int = 4096) -> None:
         if capacity < 1:
@@ -116,8 +102,7 @@ class TraceBuffer:
             self._next = 0
             self._count = 0
 
-    def samples(self, phase: str | None = None,
-                kind: str | None = None) -> list[PhaseSample]:
+    def samples(self, phase: str | None = None) -> list[PhaseSample]:
         """Oldest-first view, optionally filtered."""
         with self._lock:
             if self._count < self.capacity:
@@ -126,8 +111,7 @@ class TraceBuffer:
                 ordered = self._buf[self._next:] + self._buf[: self._next]
         return [s for s in ordered
                 if s is not None
-                and (phase is None or s.phase == phase)
-                and (kind is None or s.kind == kind)]
+                and (phase is None or s.phase == phase)]
 
     def phases(self) -> list[str]:
         seen: dict[str, None] = {}
@@ -136,55 +120,11 @@ class TraceBuffer:
         return list(seen)
 
     # ------------------------------------------------------------------
-    def design_matrix(self, phase: str, *, kind: str = "exec",
-                      ) -> tuple[np.ndarray, np.ndarray]:
+    def design_matrix(self, phase: str) -> tuple[np.ndarray, np.ndarray]:
         """(X, y) for the calibrator: X (n, 4) features, y (n,) wall ms."""
-        sel = self.samples(phase, kind)
+        sel = self.samples(phase)
         if not sel:
             return np.zeros((0, N_FEATURES)), np.zeros(0)
         X = np.stack([s.features for s in sel])
         y = np.array([s.wall_ms for s in sel], dtype=np.float64)
         return X, y
-
-    # ------------------------------------------------------------------
-    def to_chrome_trace(self) -> dict:
-        """Chrome-trace ("traceEvents") JSON object.
-
-        One pid per phase, one tid per shard; ``exec`` samples become
-        complete ("X") events.  Samples without an explicit ``ts_ms``
-        are laid out back-to-back per (phase, shard) track in arrival
-        order, so relative durations stay meaningful even when the
-        producer never recorded absolute timestamps.
-        """
-        events: list[dict] = []
-        pids: dict[str, int] = {}
-        cursor: dict[tuple[str, int], float] = {}
-        for s in self.samples():
-            pid = pids.setdefault(s.phase, len(pids) + 1)
-            key = (s.phase, s.shard)
-            if s.ts_ms is not None:
-                ts = s.ts_ms
-                cursor[key] = max(cursor.get(key, 0.0), ts + s.wall_ms)
-            else:
-                ts = cursor.get(key, 0.0)
-                cursor[key] = ts + s.wall_ms
-            events.append({
-                "name": f"{s.phase}/{s.kind}",
-                "cat": s.kind,
-                "ph": "X",
-                "pid": pid,
-                "tid": s.shard,
-                "ts": ts * 1e3,  # chrome trace wants microseconds
-                "dur": s.wall_ms * 1e3,
-                "args": {"step": s.step,
-                         **{n: float(v)
-                            for n, v in zip(FEATURE_NAMES, s.features)}},
-            })
-        for phase, pid in pids.items():
-            events.append({"name": "process_name", "ph": "M", "pid": pid,
-                           "args": {"name": f"phase:{phase}"}})
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-    def export_chrome_trace(self, path) -> None:
-        with open(path, "w") as f:
-            json.dump(self.to_chrome_trace(), f)

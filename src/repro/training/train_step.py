@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, with_attention_backend
 from repro.core.communicator import apply_comm_plan
 from repro.models.model import forward
+from repro.obs.spans import phase
 from repro.training.optimizer import AdamWConfig, adamw_init, adamw_update
 
 __all__ = [
@@ -145,7 +146,9 @@ def make_train_step(
 
     def train_step(params, opt_state, batch):
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
-        params, opt_state, opt_metrics = adamw_update(params, grads, opt_state, opt_cfg)
+        with phase("optimizer"):
+            params, opt_state, opt_metrics = adamw_update(params, grads, opt_state,
+                                                          opt_cfg)
         return params, opt_state, {**metrics, **opt_metrics}
 
     return train_step

@@ -1,6 +1,6 @@
 """Observability plane tests: quantile sketch rank error, registry
 semantics, OpenMetrics exposition, canonical ledger formulas, flight
-recorder crash safety, alert routing, unified timeline, kernel hooks.
+recorder crash safety, alert routing, kernel hooks.
 """
 import json
 import os
@@ -17,7 +17,7 @@ from hypothesis import strategies as st
 
 from repro.obs import (AlertBridge, FlightRecorder, GapWaterfall,
                        MetricsRegistry, QuantileSketch, StepLedger,
-                       build_timeline, get_registry, goodput_fraction,
+                       get_registry, goodput_fraction,
                        phase_imbalance, read_flight_record,
                        render_openmetrics, set_registry, simulated_mfu,
                        straggler_overhead, write_openmetrics)
@@ -296,11 +296,10 @@ def test_step_ledger_records_series_and_alerts():
     mfu = reg.get("train_mfu_simulated").labels().value
     assert 0.0 < mfu < 1.0
     assert reg.get("train_metric").labels(name="loss").value == 2.5
-    # per-phase imbalance series tracked for the timeline
+    # per-phase imbalance series tracked for the anomaly monitor
     assert [s for s, _ in led.series["mfu_simulated"]] == [0, 1]
     assert led.series["imbalance_llm"][0][1] == pytest.approx(
         4.0 / 2.5 - 1.0)
-    assert led.step_ts_ms[1] == pytest.approx(100.0)
     s = led.summary()
     assert s["steps"] == 3 and s["tokens"] == 128.0
     assert s["step_ms_p50"] == pytest.approx(50.0)
@@ -404,59 +403,6 @@ def test_alert_bridge_routes_all_signal_shapes(tmp_path):
     assert "alerts{alert=preemption_storm}" in snap
 
 
-# ----------------------------------------------------------------------
-# Unified timeline.
-# ----------------------------------------------------------------------
-def test_build_timeline_merges_sources():
-    from repro.serving.engine.engine import StepTiming
-
-    led = StepLedger(d=2, registry=MetricsRegistry())
-    led.record_step(0, report=_fake_report({"llm": [1.0, 2.0]}),
-                    step_ms=10.0)
-    led.record_step(1, report=_fake_report({"llm": [1.0, 1.0]}),
-                    step_ms=10.0)
-    timings = [StepTiming(step=0, schedule_ms=0.5, prefill_ms=3.0,
-                          decode_ms=1.0, n_prefill_seqs=2,
-                          prefill_tokens=64, n_decode_seqs=1),
-               StepTiming(step=1, schedule_ms=0.4, prefill_ms=0.0,
-                          decode_ms=1.2, n_prefill_seqs=0,
-                          prefill_tokens=0, n_decode_seqs=3)]
-    doc = build_timeline(step_timings=timings, ledger=led,
-                         series={"extra": [(0, 1.0)]})
-    assert doc["displayTimeUnit"] == "ms"
-    evs = doc["traceEvents"]
-    spans = [e for e in evs if e["ph"] == "X"]
-    counters = [e for e in evs if e["ph"] == "C"]
-    metas = [e for e in evs if e["ph"] == "M"]
-    # engine spans live in the engine pid block, back to back in time
-    assert {e["pid"] for e in spans} == {1000}
-    decode0 = next(e for e in spans if e["name"] == "decode"
-                   and e["args"]["step"] == 0)
-    sched1 = next(e for e in spans if e["name"] == "schedule"
-                  and e["args"]["step"] == 1)
-    assert sched1["ts"] == pytest.approx(decode0["ts"] + decode0["dur"])
-    # counter tracks: ledger series + caller extras on the counter pid,
-    # timestamped by the ledger's cumulative wall clock
-    names = {e["name"] for e in counters}
-    assert {"mfu_simulated", "imbalance_llm", "extra"} <= names
-    assert all(e["pid"] == 9000 for e in counters)
-    mfu_pts = sorted(e["ts"] for e in counters
-                     if e["name"] == "mfu_simulated")
-    assert mfu_pts == [10.0 * 1e3, 20.0 * 1e3]
-    assert any(e["args"]["name"] == "engine:replica0" for e in metas)
-
-
-def test_timeline_includes_orchestrator_trace_spans():
-    from repro.telemetry.trace import PhaseSample, TraceBuffer
-
-    buf = TraceBuffer()
-    buf.add(PhaseSample.from_lengths("llm", [4, 8], 2.0, kind="plan"))
-    buf.add(PhaseSample.from_lengths("vision", [2], 1.0, kind="exec"))
-    doc = build_timeline(trace_buffer=buf)
-    spans = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
-    assert {e["name"] for e in spans} >= {"llm/plan", "vision/exec"}
-
-
 def test_ledger_flags_inconsistent_clocks():
     """exposed_ms > step_ms means the host and step clocks disagree;
     the ledger must surface that as an alert event, not clamp silently."""
@@ -468,29 +414,6 @@ def test_ledger_flags_inconsistent_clocks():
     assert bad[0]["exposed_ms"] == 25.0 and bad[0]["step_ms"] == 10.0
     # the clamp still applies to the goodput gauge itself
     assert 0.0 <= led.series["goodput_frac"][-1][1] <= 1.0
-
-
-def test_timeline_checkpoint_track_and_waterfall_counters():
-    from repro.checkpoint import CheckpointOp
-
-    ops = [CheckpointOp(kind="save", step=4, start_s=100.0, wall_ms=30.0),
-           CheckpointOp(kind="restore", step=4, start_s=102.0, wall_ms=12.0)]
-    wf = GapWaterfall(registry=MetricsRegistry())
-    wf.observe(0, phase_costs={"llm": [1.0, 2.0]}, step_ms=5.0)
-    doc = build_timeline(checkpoint_ops=ops, waterfall=wf)
-    evs = doc["traceEvents"]
-    spans = [e for e in evs if e.get("ph") == "X"]
-    # checkpoint ops render on their own pid, at real relative offsets
-    save = next(e for e in spans if e["name"] == "save@step4")
-    restore = next(e for e in spans if e["name"] == "restore@step4")
-    assert save["pid"] == restore["pid"] == 8000
-    assert save["ts"] == 0.0 and restore["ts"] == pytest.approx(2e6)
-    assert save["dur"] == pytest.approx(30e3)
-    metas = [e for e in evs if e.get("ph") == "M"]
-    assert any(e["args"]["name"] == "checkpoint" for e in metas)
-    # waterfall series join the counter pid under a waterfall_ prefix
-    counters = {e["name"] for e in evs if e.get("ph") == "C"}
-    assert {"waterfall_gap", "waterfall_imbalance_llm"} <= counters
 
 
 def test_step_timing_carries_preemption_fields():

@@ -26,7 +26,6 @@ from repro.launch.mesh import (dp_shards_of, make_production_mesh,
 from repro.obs.decompose import GapWaterfall
 from repro.obs.ledger import StepLedger
 from repro.obs.registry import MetricsRegistry
-from repro.obs.timeline import build_timeline
 from repro.sharding.specs import stage_partition
 
 EPS = 1e-9
@@ -423,21 +422,3 @@ def test_ledger_record_pipeline():
         plan.fill_fraction)
     assert ledger.series["pipeline_mfu_uplift"][0][1] == pytest.approx(
         plan.mfu_uplift)
-
-
-def test_timeline_pipeline_lanes():
-    plan = _plan(d=2, per=32, pp=4, m=8, seed=9)
-    doc = build_timeline(pipeline=plan)
-    ev = doc["traceEvents"]
-    lanes = [e for e in ev if e.get("ph") == "M"
-             and e["name"] == "thread_name" and e["pid"] == 7000]
-    assert len(lanes) == plan.pp
-    assert lanes[0]["args"]["name"].startswith("stage0 (")
-    spans = [e for e in ev if e.get("ph") == "X" and e["pid"] == 7000]
-    assert spans and all(e["dur"] >= 0 for e in spans)
-    cats = {e["cat"] for e in spans}
-    assert cats >= {"fwd", "bwd"}
-    assert "enc_fill" in cats  # encoder chunks render in the bubbles
-    procs = [e for e in ev if e.get("ph") == "M" and e["name"] == "process_name"
-             and e["pid"] == 7000]
-    assert procs[0]["args"]["name"] == f"pipeline:rank{plan.critical_rank}"
