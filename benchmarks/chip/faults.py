@@ -6,6 +6,10 @@ replacement for the program's ``make_train_step`` factory.
   parameters and optimizer state it was given.
 * ``half_batch``: the step leaves out the labels of the second half of
   the DP shards, so its loss is the mean over the rest.
+* ``no_exchange``: the communicator's exchange is left out; each DP
+  shard's encoder tokens stay where the encoder phase put them instead
+  of moving to the shards and slots the LLM phase planned for them
+  (across chips on a mesh).
 """
 from __future__ import annotations
 
@@ -37,4 +41,25 @@ def half_batch(cfg, opt_cfg, **kw):
     return broken
 
 
-FAULTS = {"unchanged_state": unchanged_state, "half_batch": half_batch}
+def no_exchange(cfg, opt_cfg, **kw):
+    from repro.training import train_step
+
+    def keep_in_place(cfg, mesh, dp_axes, *, mode="a2a"):
+        def exchange_factory(batch):
+            def exchange(name, enc_tok):
+                cap = batch[f"enc_{name}_plan_post_mask"].shape[-1]
+                kept = enc_tok[:, :cap]
+                return jnp.pad(kept, ((0, 0), (0, cap - kept.shape[1]), (0, 0)))
+            return exchange
+        return exchange_factory
+
+    planned = train_step.make_exchange
+    train_step.make_exchange = keep_in_place
+    try:
+        return train_step.make_train_step(cfg, opt_cfg, **kw)
+    finally:
+        train_step.make_exchange = planned
+
+
+FAULTS = {"unchanged_state": unchanged_state, "half_batch": half_batch,
+          "no_exchange": no_exchange}

@@ -152,8 +152,45 @@ def llm_grad(p, enc_rows, tokens, is_text, labels, n, *, shape, quant):
     return loss, gp, ge
 
 
-_add_donate = jax.jit(lambda acc, g: jax.tree_util.tree_map(jnp.add, acc, g),
-                      donate_argnums=0)
+# ----------------------------------------------------------------------
+# Data movement and accumulation between the gradient programs, each one
+# jitted call whatever an example's lengths (they are operands here).
+# ----------------------------------------------------------------------
+@jax.jit
+def _place_rows(enc_rows, out, start, rows):
+    """enc_rows with out[:rows] written at rows start .. start + rows - 1."""
+    i = jnp.arange(enc_rows.shape[0]) - start
+    hit = (i >= 0) & (i < rows)
+    return jnp.where(hit[:, None], out[jnp.clip(i, 0, out.shape[0] - 1)], enc_rows)
+
+
+@functools.partial(jax.jit, static_argnames=("length",))
+def _take_rows(ge, start, rows, *, length):
+    """[length, D]: ge's rows start .. start + rows - 1, then zeros."""
+    i = jnp.arange(length)
+    return jnp.where((i < rows)[:, None], ge[jnp.clip(start + i, 0, ge.shape[0] - 1)], 0.0)
+
+
+@jax.jit
+def _zeros_like(tree):
+    return jax.tree_util.tree_map(jnp.zeros_like, tree)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _accumulate(acc, g):
+    """acc + g, where g holds the top-level subtrees an example reached."""
+    return {k: jax.tree_util.tree_map(jnp.add, v, g[k]) if k in g else v
+            for k, v in acc.items()}
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _divide(tree, count):
+    return jax.tree_util.tree_map(lambda a: a / count, tree)
+
+
+@jax.jit
+def _square_sums(leaves):
+    return [jnp.sum(jnp.square(g)) for g in leaves]
 
 
 # ----------------------------------------------------------------------
@@ -235,7 +272,7 @@ def batch_grads(params, examples, model: dict, quant=None, *,
             out = encoder_forward(params[f"encoder_{name}"], emb, n, enc=key,
                                   theta=theta, quant=quant)
             rows = -(-n // e["downsample"])
-            enc_rows = jax.lax.dynamic_update_slice_in_dim(enc_rows, out[:rows], start, 0)
+            enc_rows = _place_rows(enc_rows, out, start, rows)
             enc_in[name] = (emb, n, key, start, rows, out.shape[0])
         pad = Lb - L
         ls, gp, ge = llm_grad(
@@ -243,23 +280,15 @@ def batch_grads(params, examples, model: dict, quant=None, *,
             np.pad(labels, (0, pad), constant_values=-1), L, shape=shape, quant=quant)
         g = dict(gp)
         for name, (emb, n, key, start, rows, tout) in enc_in.items():
-            cot = jnp.zeros((tout, D), jnp.float32).at[:rows].set(
-                jax.lax.dynamic_slice_in_dim(ge, start, rows, 0))
+            cot = _take_rows(ge, start, rows, length=tout)
             g[f"encoder_{name}"] = encoder_grad(
                 params[f"encoder_{name}"], emb, n, cot, enc=key, theta=theta, quant=quant)
         if acc is None:
-            acc = jax.tree_util.tree_map(jnp.zeros_like, params)
-        acc = _add_donate(acc, _fill(acc, g))
+            acc = _zeros_like(params)
+        acc = _accumulate(acc, g)
         loss_sum += float(ls)
         count += int((labels >= 0).sum())
-    grads = jax.tree_util.tree_map(lambda a: a / count, acc)
-    return loss_sum / count, grads, count
-
-
-def _fill(like, g):
-    """g with zero leaves where an example did not reach a subtree."""
-    return {k: (g[k] if k in g else jax.tree_util.tree_map(jnp.zeros_like, v))
-            for k, v in like.items()}
+    return loss_sum / count, _divide(acc, count), count
 
 
 # ----------------------------------------------------------------------
@@ -286,7 +315,7 @@ def adam_step(params, grads, state, opt: dict, keep=jnp.bfloat16):
     scale)."""
     leaves, tdef = jax.tree_util.tree_flatten(params)
     gl = tdef.flatten_up_to(grads)
-    gnorm = np.sqrt(sum(float(jnp.sum(jnp.square(g))) for g in gl))
+    gnorm = np.sqrt(sum(float(s) for s in _square_sums(gl)))
     scale = min(1.0, opt["clip_norm"] / (gnorm + 1e-9))
     t = state["t"] + 1
     mus = state["mu"] or [np.zeros(p.shape, np.float32) for p in leaves]

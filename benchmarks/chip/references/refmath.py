@@ -45,9 +45,16 @@ def matrix(*shape) -> tuple:
     return shape, 1.0 / math.sqrt(shape[-2])
 
 
+@jax.jit
+def _norms(tree):
+    return jax.tree_util.tree_map(
+        lambda v: jnp.linalg.norm(v.astype(jnp.float32).ravel()), tree)
+
+
 def leaf_norms(tree) -> dict[str, float]:
-    return {jax.tree_util.keystr(k): float(jnp.linalg.norm(v.astype(jnp.float32).ravel()))
-            for k, v in jax.tree_util.tree_leaves_with_path(tree)}
+    """Each leaf's float32 norm, by key path; one jitted call for the tree."""
+    return {jax.tree_util.keystr(k): float(v)
+            for k, v in jax.tree_util.tree_leaves_with_path(_norms(tree))}
 
 
 @jax.custom_vjp

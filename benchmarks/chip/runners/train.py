@@ -18,6 +18,7 @@ from __future__ import annotations
 import contextlib
 import json
 import dataclasses
+import sys
 import time
 
 import jax
@@ -27,6 +28,9 @@ import numpy as np
 import traffic as traffic_mod
 
 CHECK_STEPS = 3
+# The untimed replay's compiles that take less than this are not written
+# to JAX's persistent compilation cache (see ``replay_cache``).
+REPLAY_MIN_COMPILE_S = 1.0
 # The runner's host spans, which name the idle gaps of a traced window.
 HOST_SPANS = ("loader_wait", "device_put", "dispatch", "wait_step")
 
@@ -134,9 +138,11 @@ def build(config: dict, mix: dict, seed: int, chips: int, *,
 
 
 @jax.jit
-def _norms(tree):
-    return jax.tree_util.tree_map(lambda a: jnp.linalg.norm(a.astype(jnp.float32).ravel()),
-                                  tree)
+def _change_norms(params, init):
+    """Each leaf's norm of params - init, in float32."""
+    return jax.tree_util.tree_map(
+        lambda a, b: jnp.linalg.norm((a.astype(jnp.float32) - b.astype(jnp.float32)).ravel()),
+        params, init)
 
 
 def _named(tree) -> dict[str, float]:
@@ -150,13 +156,18 @@ def run(cell, seed: int, seconds: float, trace_dir, t0: float, reference,
     readers and the correctness check read; ``counter`` is the
     configuration's FLOPs counter (``flops/<name>.py``)."""
     config, mix, model = cell.config, cell.mix, cell.config["model"]
+    stage = Stages(t0)
+    stage.done("start")
     prog = build(config, mix, seed, cell.chips, reference=reference)
+    stage.done("build")
     with (jax.set_mesh(prog.mesh) if prog.mesh is not None
           else contextlib.nullcontext()):
         try:
             readings = _check_steps(prog, model, seed, reference,
                                     config["optimizer"]["b1"])
+            stage.done("checked steps")
             rec = _window(prog, model, seconds, trace_dir, t0, counter)
+            stage.done("window")
         finally:
             prog.loader.close()
     rec.update(readings)
@@ -176,9 +187,45 @@ def run(cell, seed: int, seconds: float, trace_dir, t0: float, reference,
     del prog
     for a in jax.live_arrays():
         a.delete()
-    rec["checks"] = compare(rec, checked, model, config["optimizer"], seed,
-                            reference, cell.limits)
+    with replay_cache():
+        rec["checks"] = compare(rec, checked, model, config["optimizer"], seed,
+                                reference, cell.limits)
+    stage.done("replay and check")
+    rec["earlier"]["stage seconds"] = stage.seconds
     return rec
+
+
+class Stages:
+    """Wall seconds of each stage of a run, printed to standard error as
+    each ends, so that a run that stops shows where."""
+
+    def __init__(self, t0: float):
+        self.t = t0
+        self.seconds: dict[str, float] = {}
+
+    def done(self, name: str):
+        now = time.perf_counter()
+        self.seconds[name] = now - self.t
+        self.t = now
+        print(f"stage {name}: {self.seconds[name]:.1f} s", file=sys.stderr, flush=True)
+
+
+@contextlib.contextmanager
+def replay_cache():
+    """Keeps the replay's quick compiles out of the persistent compilation
+    cache.  The replay is untimed and runs hundreds of small programs;
+    written to a cache of bounded size, each would pay for an eviction
+    and could evict the timed step's entry.  Programs that take
+    ``REPLAY_MIN_COMPILE_S`` or more to compile (the reference's
+    gradients) are still kept.  JAX reads the threshold at every write,
+    so raising it here takes effect in a running process."""
+    key = "jax_persistent_cache_min_compile_time_secs"
+    old = getattr(jax.config, key)
+    jax.config.update(key, max(old, REPLAY_MIN_COMPILE_S))
+    try:
+        yield
+    finally:
+        jax.config.update(key, old)
 
 
 def _check_steps(prog: Program, model: dict, seed: int, reference, b1: float) -> dict:
@@ -194,10 +241,11 @@ def _check_steps(prog: Program, model: dict, seed: int, reference, b1: float) ->
         losses.append(float(m["loss"]))
         if k == 0:
             first_grad = {n: v / (1 - b1) for n, v in
-                          _named(_norms(prog.opt_state["mu"])).items()}
-    init = reference.init_params(model, seed, prog.params["embed"].dtype)
-    delta = _named(_norms(jax.tree_util.tree_map(
-        lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), prog.params, init)))
+                          reference.leaf_norms(prog.opt_state["mu"]).items()}
+    init = jax.device_put(
+        reference.init_params(model, seed, prog.params["embed"].dtype),
+        jax.tree_util.tree_map(lambda a: a.sharding, prog.params))
+    delta = _named(_change_norms(prog.params, init))
     del init
     return {"losses": losses, "grad_norms": first_grad, "delta_norms": delta}
 
@@ -275,12 +323,12 @@ def reference_readings(checked, model, opt, seed, reference, quant=None) -> dict
                 raw = reference.leaf_norms(grads)
                 first_grad = {n: v * scale for n, v in raw.items()}
             del grads
-        delta = reference.leaf_norms(jax.tree_util.tree_map(
-            jnp.subtract, params, _f32(reference.init_params(model, seed))))
+        delta = _named(_change_norms(params, _f32(reference.init_params(model, seed))))
     return {"losses": losses, "grad_norms": first_grad, "delta_norms": delta,
             "raw_grad_norms": raw}
 
 
+@jax.jit
 def _f32(tree):
     return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
 
@@ -342,11 +390,12 @@ def calibrate(cell, seeds, control_seeds, reference, make_train_step=None) -> li
         del prog
         for a in jax.live_arrays():
             a.delete()
-        want = reference_readings(checked, model, opt, seed, reference)
-        row = {"seed": seed, "program": gaps(readings, want)}
-        if seed in control_seeds:
-            row["control"] = gaps(reference_readings(checked, model, opt, seed,
-                                                     reference, "fp8"), want)
+        with replay_cache():
+            want = reference_readings(checked, model, opt, seed, reference)
+            row = {"seed": seed, "program": gaps(readings, want)}
+            if seed in control_seeds:
+                row["control"] = gaps(reference_readings(checked, model, opt, seed,
+                                                         reference, "fp8"), want)
         out.append(row)
         print(json.dumps(row, default=float), flush=True)
     return out

@@ -43,6 +43,8 @@ def test_benchmark_json_keeps_its_contract():
     for c in BENCH["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert c["file"].startswith("benchmarks/chip/") and (ROOT / c["file"]).is_file()
+    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
+    assert four <= max(1, len(BENCH["workloads"]) // 2), "at most half the cells, or one, take 4 chips"
     for w in BENCH["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert len(w["why"]) <= 200 and (HERE / "traffic" / f"{w['traffic']}.json").is_file()

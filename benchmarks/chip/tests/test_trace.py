@@ -2,6 +2,7 @@
 collective time and the breakdown -- on hand-made events, and on a small
 trace recorded on a TPU v5 lite (``testdata/small.xplane.pb``, made by
 ``record_trace.py``)."""
+import json
 from pathlib import Path
 
 import pytest
@@ -73,3 +74,54 @@ def test_reduce_recorded_trace():
     assert sum(t for _, t in top) <= r["busy_s"] * (1 + 1e-6)
     assert {name for name, _ in r["breakdown"]["idle_gaps"]} <= {
         "dispatch", "wait_step", "loader_wait", "no host span"}
+
+
+def test_compute_by_chip_leaves_out_collectives():
+    """Four planes, each with compute and an all-reduce or all-to-all
+    that overlaps it; the chips are in plane order."""
+    def plane(compute_end, coll):
+        return [("fusion.1", 0.0, 1.0, False), ("flash_fwd", 1.0, compute_end, True),
+                (coll, 0.5, 3.5, False)]
+
+    ev = {"device": {f"/device:TPU:{i}": plane(2.0 + i * 0.5, c) for i, c in
+                     enumerate(("all-reduce.1", "all-to-all.2", "all-reduce.1",
+                                "all-gather.4"))},
+          "host": [("dispatch", 0.0, 0.1), ("wait_step", 3.0, 4.0)]}
+    r = trace.reduce_events(ev, chips=4)
+    assert r["compute_s_by_chip"] == pytest.approx([2.0, 2.5, 3.0, 3.5])
+    # The exposed collective time is what each chip's compute leaves bare.
+    assert r["collective_exposed_s"] == pytest.approx((1.5 + 1.0 + 0.5 + 0.0) / 4)
+    assert trace.reduce_events(ev, chips=1)["compute_s_by_chip"] == pytest.approx([2.0])
+
+
+def test_recorded_trace_keeps_every_earlier_key():
+    """The keys the reduction had before ``compute_s_by_chip`` read as
+    they did (``testdata/small.reduced.json``, written by the earlier
+    reduction from the same trace)."""
+    before = json.loads((HERE / "testdata" / "small.reduced.json").read_text())
+    r = trace.reduce(RECORDED.parent, ("dispatch", "wait_step", "loader_wait"))
+    assert set(r) == set(before) | {"compute_s_by_chip"}
+    assert {k: r[k] for k in before} == before
+    assert r["compute_s_by_chip"] == pytest.approx([r["busy_s"]])
+
+
+def _reader(name):
+    return run.load_module(HERE / "metrics" / f"{name}.py")
+
+
+@pytest.mark.parametrize("by_chip, want", [
+    ([2.0, 2.0, 2.0, 2.0], 0.0), ([1.0, 1.0, 1.0, 1.4], 100 * (1.4 / 1.1 - 1)),
+    ([3.0], None), ([], None)])
+def test_rank_spread_reads_compute_by_chip(by_chip, want):
+    rec = {"kind": "train", "trace": {"compute_s_by_chip": by_chip}}
+    got = _reader("rank_spread.train").read(rec)
+    assert got == (None if want is None else pytest.approx(want))
+
+
+def test_collective_exposed_frac_reads_the_window():
+    read = _reader("collective_exposed_frac.train").read
+    t = {"collective_s": 0.8, "collective_exposed_s": 0.2, "window_s": 4.0}
+    assert read({"kind": "train", "trace": t}) == pytest.approx(5.0)
+    assert read({"kind": "train", "trace": {**t, "collective_s": 0.0,
+                                            "collective_exposed_s": 0.0}}) is None
+    assert read({"kind": "train"}) is None

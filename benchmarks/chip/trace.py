@@ -24,6 +24,9 @@ per-layer metrics read.
   all-gather, all-to-all, reduce-scatter and collective-permute
   operations, and the part of it during which no other operation ran
   on that chip.
+* ``compute_s_by_chip``: for each chip used, in plane order, the union
+  of its non-collective operation intervals inside the window: what
+  each data-parallel rank computed, apart from waiting on the others.
 * ``breakdown``: the ten operations with the most device self time
   (an event's time less that of the events nested in it), and the ten
   longest idle gaps on chip 0, each named by the host span that covers
@@ -139,6 +142,7 @@ def reduce_events(ev: dict, chips: int) -> dict:
         hi = max(o[2] for ops in device.values() for o in ops)
     planes = sorted(device)[:chips]
     busy, coll, coll_alone, by_name, kern = 0.0, 0.0, 0.0, {}, {}
+    compute = []
     for plane in planes:
         ops = [(n, max(s, lo), min(e, hi), k) for n, s, e, k in device[plane]
                if e > lo and s < hi]
@@ -151,7 +155,8 @@ def reduce_events(ev: dict, chips: int) -> dict:
         c_iv = [(s, e) for n, s, e, _ in ops if COLLECTIVE.match(n)]
         other = [(s, e) for n, s, e, _ in ops if not COLLECTIVE.match(n)]
         coll += union_length(c_iv)
-        coll_alone += union_length(c_iv + other) - union_length(other)
+        compute.append(union_length(other))
+        coll_alone += union_length(c_iv + other) - compute[-1]
     n = len(planes)
     top = sorted(by_name.items(), key=lambda kv: -kv[1])[:10]
     ops0 = [(max(o[1], lo), min(o[2], hi)) for o in device[planes[0]]
@@ -168,6 +173,7 @@ def reduce_events(ev: dict, chips: int) -> dict:
         "kernels": {k: v / n for k, v in sorted(kern.items())},
         "collective_s": coll / n,
         "collective_exposed_s": coll_alone / n,
+        "compute_s_by_chip": compute,
         "breakdown": {
             "device_ops": [[name, t / n] for name, t in top],
             "idle_gaps": [[cover((s + e) / 2), e - s] for s, e in gaps],
